@@ -81,6 +81,7 @@ import numpy as np
 from benchmarks.common import build_bench_model
 from repro.core import TrustDomain
 from repro.core.overheads import PROFILES, measured_link_tax
+from repro.launch.cache import use_compile_cache
 from repro.runtime import (Engine, FramePolicy, GenerationRequest,
                            SamplingParams, parse_mesh, stats_from_requests)
 
@@ -877,6 +878,7 @@ def main():
         from repro.launch.mesh import ensure_host_devices
         dp, tp = parse_mesh(args.mesh)
         ensure_host_devices(dp * tp)
+    use_compile_cache()
 
     cfg, model, params = build_bench_model(d_model=args.d_model,
                                            num_layers=args.layers)

@@ -9,6 +9,13 @@ any plaintext is produced (the integrity property HE schemes lack, §II).
 
 Nonces are derived per-tensor from (key id, tensor name) so no (key, nonce)
 pair is ever reused across tensors; the block counter spans within a tensor.
+
+Ciphertext rests on the host (numpy). A device tensor is sealed, and every
+tensor unsealed, on the device a chunk of leading-axis rows (about
+``CHUNK_BYTES``) at a time, at the chunk's own counter base: the device
+holds the tensor plus one chunk, never a second copy of the tensor. A host
+(numpy) tensor is packed on the host. Either way the ciphertext and MAC are
+byte-identical to sealing the tensor's bytes whole.
 """
 
 from __future__ import annotations
@@ -16,6 +23,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import hmac
+import math
 import os
 from typing import Any, Dict, Tuple
 
@@ -24,8 +32,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
+from repro.kernels.chacha20 import BLOCKS_PER_TILE
 
 Params = Any
+
+# device tensors seal/unseal in chunks of rows of about this size
+CHUNK_BYTES = 64 << 20
 
 
 class IntegrityError(Exception):
@@ -95,7 +107,7 @@ def shared_page_name(content_key: bytes, kpath: str) -> str:
 @dataclasses.dataclass
 class SealedTensor:
     name: str
-    ciphertext: jax.Array    # uint32 [16, N] blocked layout
+    ciphertext: np.ndarray   # uint32 [16, N] blocked layout, on the host
     mac: bytes
     shape: Tuple[int, ...]
     dtype: str
@@ -105,21 +117,52 @@ class SealedTensor:
         return f"{self.name}|{self.shape}|{self.dtype}|{self.n_bytes}".encode()
 
 
-def _mac(key: SealingKey, sealed_header: bytes, ciphertext: jax.Array) -> bytes:
+def _mac(key: SealingKey, sealed_header: bytes, ciphertext) -> bytes:
     h = hmac.new(key.mac_key, sealed_header, hashlib.sha256)
-    h.update(np.asarray(ciphertext).tobytes())
+    h.update(np.ascontiguousarray(ciphertext).view(np.uint8).reshape(-1))
     return h.digest()
 
 
-def seal_tensor(key: SealingKey, name: str, array: jax.Array) -> SealedTensor:
-    arr = np.asarray(array)
-    raw = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
-    blocked, n_bytes = ops.pack_u32(raw)
+def _rows_shape(shape: Tuple[int, ...]) -> Tuple[int, ...]:
+    """A tensor's shape as leading-axis rows (a scalar is one row)."""
+    return tuple(shape) or (1,)
+
+
+def _row_chunks(shape: Tuple[int, ...], itemsize: int):
+    """Leading-axis row ranges of about CHUNK_BYTES, each but the last a
+    whole number of kernel tiles (so every chunk starts on a tile of the
+    tensor's blocked layout): ``[(start, rows, first_block), ...]``."""
+    row_bytes = math.prod(shape[1:]) * itemsize
+    if row_bytes == 0:
+        return []
+    tile_bytes = 64 * BLOCKS_PER_TILE
+    step = tile_bytes // math.gcd(row_bytes, tile_bytes)
+    k = max(step, CHUNK_BYTES // row_bytes // step * step)
+    return [(a, min(k, shape[0] - a), a * row_bytes // 64)
+            for a in range(0, shape[0], k)]
+
+
+def seal_tensor(key: SealingKey, name: str, array) -> SealedTensor:
     nonce = jnp.asarray(np.frombuffer(_nonce_for(key, name), np.uint32))
-    ciphertext = ops.seal_u32(key.key_words, nonce, blocked)
+    if isinstance(array, jax.Array):
+        rows = array.reshape(_rows_shape(array.shape))
+        n_bytes = array.nbytes
+        blocks = -(-n_bytes // (64 * BLOCKS_PER_TILE)) * BLOCKS_PER_TILE
+        ciphertext = np.empty((16, blocks), np.uint32)
+        for start, n, c0 in _row_chunks(rows.shape, array.dtype.itemsize):
+            ct = np.asarray(ops.seal_u32(key.key_words, nonce,
+                                         ops.pack_rows(rows, start, n),
+                                         counter_base=c0))
+            ciphertext[:, c0:c0 + ct.shape[1]] = ct
+        shape, dtype = array.shape, array.dtype
+    else:
+        arr = np.asarray(array)
+        raw = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+        blocked, n_bytes = ops.pack_u32(raw)
+        ciphertext = np.asarray(ops.seal_u32(key.key_words, nonce, blocked))
+        shape, dtype = arr.shape, arr.dtype
     st = SealedTensor(name=name, ciphertext=ciphertext, mac=b"",
-                      shape=tuple(arr.shape), dtype=str(arr.dtype),
-                      n_bytes=n_bytes)
+                      shape=tuple(shape), dtype=str(dtype), n_bytes=n_bytes)
     st.mac = _mac(key, st.header(), ciphertext)
     return st
 
@@ -151,14 +194,22 @@ def ciphertext_page_bytes(sealed: SealedTensor) -> bytes:
 
 
 def unseal_tensor(key: SealingKey, sealed: SealedTensor) -> jax.Array:
-    expect = _mac(key, sealed.header(), sealed.ciphertext)
-    if not hmac.compare_digest(expect, sealed.mac):
-        raise IntegrityError(f"MAC mismatch for tensor '{sealed.name}'")
+    """MAC-check, then decrypt onto the device."""
+    verify_mac(key, sealed)
     nonce = jnp.asarray(np.frombuffer(_nonce_for(key, sealed.name), np.uint32))
-    blocked = ops.unseal_u32(key.key_words, nonce, sealed.ciphertext)
-    raw = ops.unpack_u32(blocked, sealed.n_bytes)
-    arr = raw.view(np.dtype(sealed.dtype)).reshape(sealed.shape)
-    return jnp.asarray(arr)
+    dtype = jnp.dtype(sealed.dtype)
+    shape = _rows_shape(sealed.shape)
+    ct = np.asarray(sealed.ciphertext)
+    out = jnp.zeros(shape, dtype)
+    per_tile = BLOCKS_PER_TILE * 64
+    for start, rows, c0 in _row_chunks(shape, dtype.itemsize):
+        chunk_bytes = rows * math.prod(shape[1:]) * dtype.itemsize
+        c1 = c0 + -(-chunk_bytes // per_tile) * BLOCKS_PER_TILE
+        blocked = ops.unseal_u32(key.key_words, nonce,
+                                 np.ascontiguousarray(ct[:, c0:c1]),
+                                 counter_base=c0)
+        out = ops.place_rows(out, blocked, start, rows)
+    return out.reshape(sealed.shape)
 
 
 # ---------------------------------------------------------------------------

@@ -15,11 +15,10 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import axis_size
 
 
 def _ring_perm(axis_name: str, shift: int = 1):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return [(i, (i + shift) % n) for i in range(n)]
 
 
@@ -30,7 +29,7 @@ def all_gather_matmul(x_local: jax.Array, w: jax.Array, axis_name: str) -> jax.A
     shard). Returns [m_l * p, n]. Each ring step matmuls the chunk currently
     held while the next chunk is in flight.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m_l = x_local.shape[0]
     out = jnp.zeros((m_l * p, w.shape[1]), jnp.promote_types(x_local.dtype, w.dtype))
@@ -54,7 +53,7 @@ def matmul_reduce_scatter(x: jax.Array, w_local: jax.Array,
     Ring: a partial-sum buffer travels the ring, each rank adding its local
     contribution for the buffer's eventual owner while computing the next.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = x.shape[0]
     assert m % p == 0, (m, p)

@@ -18,13 +18,12 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.distributed.compat import axis_size, shard_map
 
 Params = Any
 
 
 def _ring(axis_name: str):
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     return [(i, (i + 1) % n) for i in range(n)]
 
 
@@ -35,7 +34,7 @@ def gpipe_local(stage_fn: Callable[[Params, jax.Array], jax.Array],
     rank; only rank 0 consumes them). Returns [M, mb, ...] outputs valid on
     the LAST stage (zeros elsewhere).
     """
-    s = axis_size(axis_name)
+    s = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     m = microbatches.shape[0]
     carry = jnp.zeros_like(microbatches[0])
@@ -72,9 +71,9 @@ def make_gpipe(mesh: Mesh, axis_name: str,
             # broadcast final outputs to all ranks (only last stage nonzero)
             return jax.lax.psum(out, axis_name)
 
-        return shard_map(local, mesh=mesh,
-                         in_specs=(param_spec, in_spec),
-                         out_specs=out_spec,
-                         check_vma=False)(stacked_params, microbatches)
+        return jax.shard_map(local, mesh=mesh,
+                             in_specs=(param_spec, in_spec),
+                             out_specs=out_spec,
+                             check_vma=False)(stacked_params, microbatches)
 
     return pipelined

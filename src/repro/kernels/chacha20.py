@@ -20,6 +20,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_default
 
 # 128-lane multiple; 1024 blocks/tile = 64 KiB keystream per tile, well under
 # VMEM while giving the VPU long vectors.
@@ -72,12 +75,12 @@ def chacha_block_words(key_words, nonce_words, counters):
     return [s + i for s, i in zip(state, init)]
 
 
-def _xor_kernel(key_ref, nonce_ref, data_ref, out_ref, *, counter_base: int):
+def _xor_kernel(base_ref, key_ref, nonce_ref, data_ref, out_ref):
     """One tile: data (16, BLOCKS) uint32 XOR keystream for counters
     [base + pid*BLOCKS, ...)."""
     pid = pl.program_id(0)
     lane = jax.lax.broadcasted_iota(jnp.uint32, (1, data_ref.shape[1]), 1)
-    counters = (jnp.uint32(counter_base)
+    counters = (base_ref[0]
                 + pid.astype(jnp.uint32) * jnp.uint32(data_ref.shape[1]) + lane)
     key_words = [key_ref[0, i] for i in range(8)]
     nonce_words = [nonce_ref[0, i] for i in range(3)]
@@ -86,24 +89,38 @@ def _xor_kernel(key_ref, nonce_ref, data_ref, out_ref, *, counter_base: int):
     out_ref[...] = data_ref[...] ^ ks
 
 
-@functools.partial(jax.jit, static_argnames=("counter_base", "interpret"))
 def chacha20_xor_blocked(key: jax.Array, nonce: jax.Array, data: jax.Array,
-                         counter_base: int = 0, interpret: bool = True) -> jax.Array:
+                         counter_base=0,
+                         interpret: bool | None = None) -> jax.Array:
     """XOR ``data`` (uint32 [16, N], N multiple of BLOCKS_PER_TILE) with the
-    ChaCha20 keystream. Involution: applying twice returns the input."""
+    ChaCha20 keystream for counters ``counter_base ..`` (mod 2**32).
+    Involution: applying twice returns the input. ``counter_base`` (an int
+    or a uint32 scalar) is an operand, not a compile-time constant, so a
+    tensor XORed in chunks compiles once per chunk shape."""
+    return _xor_blocked(key, nonce, data,
+                        jnp.asarray(counter_base, jnp.uint32).reshape(1),
+                        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _xor_blocked(key, nonce, data, base, *, interpret):
     assert data.dtype == jnp.uint32 and data.shape[0] == 16, data.shape
     n = data.shape[1]
     assert n % BLOCKS_PER_TILE == 0, n
-    grid = (n // BLOCKS_PER_TILE,)
     return pl.pallas_call(
-        functools.partial(_xor_kernel, counter_base=counter_base),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 8), lambda i: (0, 0)),    # key words (replicated)
-            pl.BlockSpec((1, 3), lambda i: (0, 0)),    # nonce words
-            pl.BlockSpec((16, BLOCKS_PER_TILE), lambda i: (0, i)),
-        ],
-        out_specs=pl.BlockSpec((16, BLOCKS_PER_TILE), lambda i: (0, i)),
+        _xor_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                        # counter base
+            grid=(n // BLOCKS_PER_TILE,),
+            in_specs=[
+                pl.BlockSpec((1, 8), lambda i, base: (0, 0)),   # key words
+                pl.BlockSpec((1, 3), lambda i, base: (0, 0)),   # nonce words
+                pl.BlockSpec((16, BLOCKS_PER_TILE), lambda i, base: (0, i)),
+            ],
+            out_specs=pl.BlockSpec((16, BLOCKS_PER_TILE),
+                                   lambda i, base: (0, i)),
+        ),
         out_shape=jax.ShapeDtypeStruct(data.shape, jnp.uint32),
-        interpret=interpret,
-    )(key.reshape(1, 8), nonce.reshape(1, 3), data)
+        interpret=(interpret_default() if interpret is None
+                   else interpret),
+    )(base, key.reshape(1, 8), nonce.reshape(1, 3), data)

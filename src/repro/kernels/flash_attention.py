@@ -22,6 +22,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 NEG_INF = -1e30
 
 
@@ -67,7 +69,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 @functools.partial(jax.jit, static_argnames=("bq", "bkv", "interpret"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     bq: int = 128, bkv: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """Causal attention. q/k/v: [bh, s, d] with s % bq == s % bkv == 0."""
     bh, s, d = q.shape
     assert k.shape == v.shape == (bh, s, d)
@@ -90,5 +92,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # output accumulator
         ],
-        interpret=interpret,
+        interpret=(interpret_default() if interpret is None
+                   else interpret),
     )(q, k, v)

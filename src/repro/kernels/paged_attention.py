@@ -37,8 +37,9 @@ whenever ``interpret=True``) therefore runs the *same* page walk — same
 table dereference, same ``_attend_page_math`` update per page, same
 masking — as a ``lax.fori_loop`` over pages under ``vmap`` over slots,
 touching each mapped page exactly once. Tests pin the emulation bit-exact
-against the Pallas kernel's interpret output; compiled TPU runs
-(``interpret=False``) always take the real ``pallas_call``.
+against the Pallas kernel's interpret output. ``interpret`` defaults from
+the platform (:func:`repro.kernels.interpret_default`): on a TPU backend
+both kernels always compile the real ``pallas_call``.
 """
 
 from __future__ import annotations
@@ -51,11 +52,12 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
 from repro.kernels.chacha20 import chacha_block_words
 
 NEG_INF = -1e30
 
-# pool dtypes the in-kernel XOR path supports (bitcast to a lane-word view);
+# pool dtypes the in-kernel XOR path supports (bitcast to 32/16-bit words);
 # anything else restores through the host-decrypt path instead.
 FUSED_DTYPES = (jnp.float32, jnp.bfloat16)
 
@@ -67,41 +69,114 @@ def supports_fused_unseal(dtype, page_bytes: int) -> bool:
         jnp.dtype(d) for d in FUSED_DTYPES)
 
 
-def _page_keystream(key_ref, nonce, layer, bpp: int) -> jax.Array:
-    """Linear uint32 keystream for one page: ``bpp`` ChaCha20 blocks at
-    counter_base = layer * bpp (core/sealing.py packs a page's L layers
-    contiguously, layer l at blocks [l*bpp, (l+1)*bpp)). Linear word i is
-    word i%16 of counter block i//16 — the same permutation ops.pack_u32's
-    ``.T.reshape(-1)`` applies when serializing blocked ciphertext."""
-    counters = (layer.astype(jnp.uint32) * jnp.uint32(bpp)
-                + jax.lax.broadcasted_iota(jnp.uint32, (1, bpp), 1))
-    key_words = [key_ref[i] for i in range(8)]
-    words = chacha_block_words(key_words, list(nonce), counters)
-    return jnp.stack(words, axis=-1).reshape(-1)        # [bpp * 16]
+LANES = 128
 
 
-def _unseal_tile(tile: jax.Array, crypt_row, key_ref, layer,
+def _page_words(key_words, nonce, layer, bpp: int, row0: int = 0,
+                rows: int | None = None):
+    """Keystream of one page's sealed bytes, each ChaCha20 block computed
+    once: the 16 word planes of blocks ``row0*128 ..`` of the page (counter
+    layer * bpp + k, as core/sealing.py packs a page's L layers
+    contiguously, layer l at blocks [l*bpp, (l+1)*bpp)), block k at
+    ``[k // 128 - row0, k % 128]`` — the block index on the lanes."""
+    rows = -(-bpp // LANES) - row0 if rows is None else rows
+    shape = (rows, LANES)
+    k = ((jax.lax.broadcasted_iota(jnp.int32, shape, 0) + row0) * LANES
+         + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+    return chacha_block_words(key_words, nonce,
+                              layer.astype(jnp.uint32) * jnp.uint32(bpp)
+                              + k.astype(jnp.uint32))
+
+
+def _xor_bits(t: jax.Array, ks: jax.Array) -> jax.Array:
+    """``t`` XOR ``ks`` (uint32, t's shape, one keystream unit per
+    element in its low bits), keeping t's dtype."""
+    bits_dtype = jnp.uint32 if t.dtype.itemsize == 4 else jnp.uint16
+    bits = jax.lax.bitcast_convert_type(t, bits_dtype).astype(jnp.uint32)
+    return jax.lax.bitcast_convert_type((bits ^ ks).astype(bits_dtype),
+                                        t.dtype)
+
+
+def _unseal_tile(tile: jax.Array, crypt_row, key_words, layer,
                  bpp: int) -> jax.Array:
-    """XOR a KV page tile with its keystream iff its crypt flag is live.
-    The flag-dead branch must be bit-exact identity (plaintext pages share
-    the same code path), hence where() on the bitcast words."""
+    """The emulation's whole-page unseal: decrypt iff the crypt flag is
+    live; a plaintext page (flag 0) passes through bit-exactly."""
+    def decrypt(t):
+        words = _page_words(key_words, crypt_row[:3], layer, bpp)
+        stream = jnp.stack([w.reshape(-1) for w in words], -1).reshape(-1)
+        if t.dtype.itemsize == 2:       # little-endian halves of each word
+            stream = jnp.stack([stream & jnp.uint32(0xFFFF),
+                                stream >> jnp.uint32(16)], -1).reshape(-1)
+        return _xor_bits(t, stream[:t.size].reshape(t.shape))
+
+    return jax.lax.cond(crypt_row[3] > 0, decrypt, lambda t: t, tile)
+
+
+def _unseal_into(src_ref, dst_ref, ks_ref, crypt_row, key_words, layer,
+                 bpp: int) -> None:
+    """The kernel's unseal: copy page ``src_ref[0]`` into VMEM scratch
+    ``dst_ref``, decrypting it when its crypt flag is live.
+
+    The page's keystream is computed once, block index on the lanes, into
+    ``ks_ref`` [16 words, blocks // 128, 128]. Each page row then picks its
+    elements' words with a lane gather: element e (row-major over the page)
+    covers stream word w = e * itemsize // 4, word w % 16 of block w // 16,
+    and a 2-byte element is the low (even e) or high (odd e) half of it.
+    Same keystream and XOR as :func:`_unseal_tile`, so both produce the
+    same bits."""
     live = crypt_row[3] > 0
-    ks32 = _page_keystream(key_ref, (crypt_row[0], crypt_row[1],
-                                     crypt_row[2]), layer, bpp)
-    if tile.dtype == jnp.dtype(jnp.float32):
-        bits = jax.lax.bitcast_convert_type(tile, jnp.uint32).reshape(-1)
-        plain = jnp.where(live, bits ^ ks32, bits)
-        return jax.lax.bitcast_convert_type(
-            plain.reshape(tile.shape), jnp.float32)
-    # bfloat16: element e occupies bytes [2e, 2e+2) little-endian, so the
-    # keystream word for elements (2w, 2w+1) splits into (low, high) halves.
-    lo = (ks32 & jnp.uint32(0xFFFF)).astype(jnp.uint16)
-    hi = (ks32 >> jnp.uint32(16)).astype(jnp.uint16)
-    ks16 = jnp.stack([lo, hi], axis=-1).reshape(-1)
-    bits = jax.lax.bitcast_convert_type(tile, jnp.uint16).reshape(-1)
-    plain = jnp.where(live, bits ^ ks16, bits)
-    return jax.lax.bitcast_convert_type(
-        plain.reshape(tile.shape), jnp.bfloat16)
+    ps, shape = dst_ref.shape[0], dst_ref.shape[1:]
+    itemsize = dst_ref.dtype.itemsize
+    row_elems = int(np.prod(shape))
+    n_rows = ks_ref.shape[1]
+    chunk = 8 if n_rows % 8 == 0 else n_rows     # one vreg of blocks
+
+    def first_row(p):          # ks row holding the page row's first word
+        return p * row_elems * itemsize // 64 // LANES
+
+    # ks rows one page row spans, at most
+    span = max((((p + 1) * row_elems * itemsize - 1) // 64 // LANES)
+               - first_row(p) + 1 for p in range(ps))
+
+    @pl.when(live)
+    def _cipher():
+        for c0 in range(0, n_rows, chunk):
+            words = _page_words(key_words, crypt_row[:3], layer, bpp,
+                                row0=c0, rows=chunk)
+            for n in range(16):
+                ks_ref[n, c0:c0 + chunk] = words[n]
+        e0 = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        for axis in range(1, len(shape)):
+            e0 = e0 * shape[axis] + jax.lax.broadcasted_iota(
+                jnp.int32, shape, axis)
+        wide = shape[:-1] + (LANES,)
+
+        def row(p, carry):
+            e = e0 + p * row_elems
+            w = e if itemsize == 4 else e >> 1
+            blk = w >> 4
+            r0 = first_row(p)
+            dr = (blk >> 7) - r0
+            lane = blk & (LANES - 1)
+            word = w & 15
+            ks = jnp.zeros(shape, jnp.uint32)
+            for k in range(span):
+                r = jnp.minimum(r0 + k, n_rows - 1)
+                for n in range(16):
+                    src = jnp.broadcast_to(ks_ref[n, pl.ds(r, 1), :], wide)
+                    got = jnp.take_along_axis(src, lane, axis=-1,
+                                              mode="promise_in_bounds")
+                    ks = jnp.where((word == n) & (dr == k), got, ks)
+            if itemsize == 2:
+                ks = jnp.where((e & 1) == 1, ks >> jnp.uint32(16),
+                               ks & jnp.uint32(0xFFFF))
+            dst_ref[p] = _xor_bits(src_ref[0, p], ks)
+            return carry
+        jax.lax.fori_loop(0, ps, row, 0)
+
+    @pl.when(jnp.logical_not(live))
+    def _plain():
+        dst_ref[...] = src_ref[0]
 
 
 def _attend_page_math(q32, k, v, j, valid, m_prev, l_prev, acc_prev, *,
@@ -165,9 +240,9 @@ def _paged_kernel(table_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _paged_unseal_kernel(table_ref, valid_ref, layer_ref, key_ref,
-                         q_ref, k_ref, v_ref, kc_ref, vc_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, scale: float,
-                         page_size: int, pages: int, bpp: int):
+                         kc_ref, vc_ref, q_ref, k_ref, v_ref, o_ref,
+                         m_ref, l_ref, acc_ref, kd_ref, vd_ref, ks_ref, *,
+                         scale: float, page_size: int, pages: int, bpp: int):
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -181,11 +256,16 @@ def _paged_unseal_kernel(table_ref, valid_ref, layer_ref, key_ref,
 
     @pl.when(j * page_size < valid)
     def _compute():
-        k = _unseal_tile(k_ref[0], kc_ref[0], key_ref, layer, bpp)
-        v = _unseal_tile(v_ref[0], vc_ref[0], key_ref, layer, bpp)
-        _attend_page(q_ref[0].astype(jnp.float32), k, v, j, valid,
-                     m_ref, l_ref, acc_ref, scale=scale,
-                     page_size=page_size)
+        key_words = [key_ref[w] for w in range(8)]
+        _unseal_into(k_ref, kd_ref, ks_ref,
+                     [kc_ref[i, 4 * j + w] for w in range(4)],
+                     key_words, layer, bpp)
+        _unseal_into(v_ref, vd_ref, ks_ref,
+                     [vc_ref[i, 4 * j + w] for w in range(4)],
+                     key_words, layer, bpp)
+        _attend_page(q_ref[0].astype(jnp.float32), kd_ref[...],
+                     vd_ref[...], j, valid, m_ref, l_ref, acc_ref,
+                     scale=scale, page_size=page_size)
 
     @pl.when(j == pages - 1)
     def _emit():
@@ -240,7 +320,7 @@ def _specs(h, hd, ps, hk, n_prefetch):
 @functools.partial(jax.jit, static_argnames=("interpret", "emulate"))
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     table: jax.Array, valid: jax.Array, *,
-                    interpret: bool = True,
+                    interpret: bool | None = None,
                     emulate: bool | None = None) -> jax.Array:
     """Decode attention over a paged KV pool, no dense gather.
 
@@ -259,6 +339,8 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     _, ps, hk, _ = k_pool.shape
     pages = table.shape[1]
     assert h % hk == 0, (h, hk)
+    if interpret is None:
+        interpret = interpret_default()
     if emulate is None:
         emulate = interpret
     if emulate:
@@ -293,7 +375,7 @@ def paged_attention_unseal(q: jax.Array, k_pool: jax.Array,
                            valid: jax.Array, layer: jax.Array,
                            key_words: jax.Array, k_crypt: jax.Array,
                            v_crypt: jax.Array, *, blocks_per_page: int,
-                           interpret: bool = True,
+                           interpret: bool | None = None,
                            emulate: bool | None = None) -> jax.Array:
     """paged_attention over a pool whose pages may be ciphertext-resident.
 
@@ -312,6 +394,8 @@ def paged_attention_unseal(q: jax.Array, k_pool: jax.Array,
     page_bytes = ps * hk * hd * jnp.dtype(k_pool.dtype).itemsize
     assert page_bytes == blocks_per_page * 64, (page_bytes, blocks_per_page)
     assert supports_fused_unseal(k_pool.dtype, page_bytes), k_pool.dtype
+    if interpret is None:
+        interpret = interpret_default()
     if emulate is None:
         emulate = interpret
     if emulate:
@@ -321,33 +405,42 @@ def paged_attention_unseal(q: jax.Array, k_pool: jax.Array,
         vc = v_crypt.astype(jnp.uint32)
 
         def unseal(phys, k, v):
-            return (_unseal_tile(k, kc[phys], key, lay, blocks_per_page),
-                    _unseal_tile(v, vc[phys], key, lay, blocks_per_page))
+            return (_unseal_tile(k, list(kc[phys]), key, lay,
+                                 blocks_per_page),
+                    _unseal_tile(v, list(vc[phys]), key, lay,
+                                 blocks_per_page))
 
         return _emulated_walk(table.astype(jnp.int32),
                               valid.astype(jnp.int32), q, k_pool, v_pool,
                               unseal=unseal)
     scale = 1.0 / np.sqrt(hd)
     q_spec, pool_spec = _specs(h, hd, ps, hk, 4)
-    crypt_spec = pl.BlockSpec(
-        (1, 4), lambda i, j, *refs: (refs[0][i, j], 0))
+    # the sidecar rows each grid step needs, gathered through the table into
+    # scalar memory: [slots, pages * 4], row i words 4j..4j+3 = page (i, j)
+    tbl = table.astype(jnp.int32)
+    kc = k_crypt.astype(jnp.uint32)[tbl].reshape(b, pages * 4)
+    vc = v_crypt.astype(jnp.uint32)[tbl].reshape(b, pages * 4)
     return pl.pallas_call(
         functools.partial(_paged_unseal_kernel, scale=scale, page_size=ps,
                           pages=pages, bpp=blocks_per_page),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=6,
             grid=(b, pages),
-            in_specs=[q_spec, pool_spec, pool_spec, crypt_spec, crypt_spec],
+            in_specs=[q_spec, pool_spec, pool_spec],
             out_specs=pl.BlockSpec((1, h, hd), lambda i, j, *refs: (i, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, 1), jnp.float32),
                 pltpu.VMEM((h, hd), jnp.float32),
+                pltpu.VMEM((ps, hk, hd), k_pool.dtype),   # decrypted k page
+                pltpu.VMEM((ps, hk, hd), v_pool.dtype),   # decrypted v page
+                # one page's keystream, [16 words, blocks // 128, 128]
+                pltpu.VMEM((16, -(-blocks_per_page // LANES), LANES),
+                           jnp.uint32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, hd), q.dtype),
         interpret=interpret,
-    )(table.astype(jnp.int32), valid.astype(jnp.int32),
+    )(tbl, valid.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
-      key_words.astype(jnp.uint32).reshape(8),
-      q, k_pool, v_pool, k_crypt, v_crypt)
+      key_words.astype(jnp.uint32).reshape(8), kc, vc, q, k_pool, v_pool)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import interpret_default
+
 
 def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, k_steps: int):
     @pl.when(pl.program_id(2) == 0)
@@ -40,7 +42,7 @@ def _qmm_kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, k_steps: int):
                    static_argnames=("bm", "bn", "bk", "out_dtype", "interpret"))
 def qmatmul(x_q: jax.Array, w_q: jax.Array, scale: jax.Array, *,
             bm: int = 128, bn: int = 128, bk: int = 128,
-            out_dtype=jnp.bfloat16, interpret: bool = True) -> jax.Array:
+            out_dtype=jnp.bfloat16, interpret: bool | None = None) -> jax.Array:
     """x_q: int8 [M, K]; w_q: int8 [K, N]; scale: f32 [1, N]
     (activation scale already folded in). Returns [M, N] ``out_dtype``.
 
@@ -62,5 +64,6 @@ def qmatmul(x_q: jax.Array, w_q: jax.Array, scale: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, s: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=(interpret_default() if interpret is None
+                   else interpret),
     )(x_q, w_q, scale)

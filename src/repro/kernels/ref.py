@@ -73,3 +73,34 @@ def flash_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     logits = jnp.where(mask[None], logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", probs, v.astype(jnp.float32)).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# paged decode attention
+# ---------------------------------------------------------------------------
+
+def paged_attention_ref(q, k_pool, v_pool, table, valid) -> np.ndarray:
+    """Oracle for paged_attention: gather each slot's pages dense, then
+    masked GQA softmax attention in f32 numpy — the same math the gather
+    decode path's sdpa performs. q [slots, h, hd]; pools [pages+1, ps, hk,
+    hd]; table [slots, max_pages]; valid [slots]. Idle rows (valid 0) are
+    zeros."""
+    b, h, hd = q.shape
+    hk = k_pool.shape[2]
+    g = h // hk
+    out = np.zeros((b, h, hd), np.float32)
+    for i in range(b):
+        n = int(valid[i])
+        if n == 0:
+            continue
+        phys = np.asarray(table[i])
+        k = np.concatenate([np.asarray(k_pool[p]) for p in phys])[:n]
+        v = np.concatenate([np.asarray(v_pool[p]) for p in phys])[:n]
+        qg = np.asarray(q[i], np.float32).reshape(hk, g, hd)
+        s = np.einsum("kgd,nkd->kgn", qg, k.astype(np.float32)) / np.sqrt(hd)
+        s = s - s.max(axis=-1, keepdims=True)
+        p = np.exp(s)
+        p /= p.sum(axis=-1, keepdims=True)
+        out[i] = np.einsum("kgn,nkd->kgd",
+                           p, v.astype(np.float32)).reshape(h, hd)
+    return out

@@ -16,8 +16,8 @@ seeded per-request sampling; ``--priority-mix`` assigns weighted priorities
 so the sealed-KV preemption path is exercised under load. ``--kv-backend
 paged`` swaps the dense slot cache for the page-pool layout (page-granular
 admission and sealing; see repro.runtime.kvcache for the selection guide).
-``--mesh dp=N[,tp=M]`` spans the engine across a device mesh (relaunching
-with forced host devices when needed) and reports the measured-vs-modeled
+``--mesh dp=N[,tp=M]`` spans the engine across a device mesh (on the CPU,
+relaunching with forced host devices when needed) and reports the measured-vs-modeled
 encrypted-interconnect (link_tax) comparison — the collective time is then
 a real all-gather on the serving mesh, not the closed-form estimate.
 ``--prefix-sharing`` (with ``--shared-prefix-len K`` to give the generated
@@ -65,6 +65,7 @@ from repro.core import RooflineTerms, TrustDomain
 from repro.core.overheads import (STEP_COMPUTE_FRACTION,
                                   STEP_MEMORY_FRACTION, fused_unseal_savings,
                                   measured_link_tax, store_restore_savings)
+from repro.launch.cache import use_compile_cache
 from repro.launch.mesh import ensure_host_devices
 from repro.models import build_model
 from repro.runtime import (Engine, FramePolicy, GenerationRequest,
@@ -280,8 +281,9 @@ def main():
                     help="give every generated prompt the same K-token head "
                          "(a shared-prefix workload for --prefix-sharing)")
     ap.add_argument("--mesh", default=None, metavar="dp=N[,tp=M]",
-                    help="span the engine across a device mesh (forces host "
-                         "devices if needed) and report measured link tax")
+                    help="span the engine across a device mesh (on the "
+                         "CPU, forces host devices if needed) and report "
+                         "measured link tax")
     ap.add_argument("--continuous-batching", action="store_true",
                     help="step-level continuous batching: prefill admissions "
                          "interleave into decode steps under a per-step "
@@ -340,6 +342,7 @@ def main():
             print(f"[mesh] rounding --slots {args.slots} up to {padded} "
                   f"(a dp={dp} mesh shards whole slots per data-shard)")
             args.slots = padded
+    use_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family == "encdec":
@@ -353,8 +356,11 @@ def main():
 
     td = TrustDomain(args.tee)
     if td.confidential:
+        # seal, then drop the plaintext: the device holds the parameters
+        # once, and load_sealed decrypts them back a chunk at a time
         sealed = td.seal_params(params)
-        params = td.load_sealed(sealed, params)
+        del params
+        params = td.load_sealed(sealed, model.abstract_params())
         verifier = td.make_verifier(cfg.name)
         quote = td.quote(verifier.challenge(), cfg.name)
         verifier.verify(quote)

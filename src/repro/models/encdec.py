@@ -78,6 +78,7 @@ class EncDecLM:
         positions = jnp.broadcast_to(jnp.arange(s)[None, :], (b, s))
 
         def body(x, lp):
+            lp = layers.layer_params("enc_layers", lp)
             h = layers.layernorm(lp["pre_norm"], x, cfg.norm_eps)
             x = x + layers.attention_forward(lp["attn"], _attn_cfg(cfg, False),
                                              h, positions)
@@ -98,6 +99,7 @@ class EncDecLM:
         x = layers.embed(params["embedding"], tokens)
 
         def body(x, lp):
+            lp = layers.layer_params("dec_layers", lp)
             h = layers.layernorm(lp["norm1"], x, cfg.norm_eps)
             x = x + layers.attention_forward(lp["self_attn"], _attn_cfg(cfg, True),
                                              h, positions)
@@ -151,6 +153,7 @@ class EncDecLM:
 
         def body(x, xs):
             lp, csl = xs
+            lp = layers.layer_params("dec_layers", lp)
             h = layers.layernorm(lp["norm1"], x, cfg.norm_eps)
             sa, kv = layers.attention_prefill(
                 lp["self_attn"], _attn_cfg(cfg, True), h,
@@ -183,6 +186,7 @@ class EncDecLM:
 
         def body(x, xs):
             lp, csl = xs
+            lp = layers.layer_params("dec_layers", lp)
             h = layers.layernorm(lp["norm1"], x, cfg.norm_eps)
             sa, kv = layers.attention_decode(
                 lp["self_attn"], _attn_cfg(cfg, True), h,

@@ -11,14 +11,44 @@ the paper's AMX-bf16 operating point (Insight 3/8).
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 Params = Any  # nested dict pytree
+
+
+# ---------------------------------------------------------------------------
+# per-layer parameter hook
+# ---------------------------------------------------------------------------
+
+_LAYER_PARAMS_HOOK: contextvars.ContextVar = contextvars.ContextVar(
+    "layer_params_hook", default=None)
+
+
+@contextlib.contextmanager
+def layer_params_hook(hook: Callable[[str, Params], Params]):
+    """While a step is traced under this context, every scanned layer's
+    parameter slice passes through ``hook(block_name, layer_params)`` before
+    use. A mesh plan uses it to all-gather FSDP-sharded weights one layer at
+    a time, inside the layer scan."""
+    token = _LAYER_PARAMS_HOOK.set(hook)
+    try:
+        yield
+    finally:
+        _LAYER_PARAMS_HOOK.reset(token)
+
+
+def layer_params(block: str, lp: Params) -> Params:
+    """``lp`` as the active :func:`layer_params_hook` sees it (unchanged
+    when none is active)."""
+    hook = _LAYER_PARAMS_HOOK.get()
+    return lp if hook is None else hook(block, lp)
 
 
 # ---------------------------------------------------------------------------

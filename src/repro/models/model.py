@@ -33,7 +33,9 @@ class Model:
 
     # -- params --------------------------------------------------------------
     def init_params(self, key) -> Params:
-        return self._impl.init_params(key)
+        """Seeded parameters, drawn under ``jit``: the device then holds
+        the outputs and none of the eager f32 draws behind them."""
+        return jax.jit(self._impl.init_params)(key)
 
     def abstract_params(self) -> Params:
         return self._impl.abstract_params()

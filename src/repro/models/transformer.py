@@ -286,6 +286,7 @@ class DecoderLM:
         def body(carry, xs):
             h, aux = carry
             lp, csl = xs
+            lp = layers.layer_params(name, lp)
             new_csl = {} if csl is not None else None
             for i, (m, f) in enumerate(slots):
                 sl = csl[f"slot_{i}"] if csl is not None else None

@@ -104,7 +104,6 @@ from repro.core.sealing import (IntegrityError, SealedTensor, SealingKey,
                                 ciphertext_page_bytes, nonce_words_for,
                                 seal_tensor, shared_page_name, unseal_tensor,
                                 verify_mac)
-from repro.kernels.ops import INTERPRET
 from repro.kernels.paged_attention import (paged_attention,
                                            paged_attention_unseal,
                                            supports_fused_unseal)
@@ -144,11 +143,22 @@ def prefix_page_keys(tokens: np.ndarray, page_size: int, written_len: int,
     return keys
 
 
+def _write_page(pool_leaf, phys, page):
+    """Write ``page`` [L, 1, ...] at pool page ``phys``. A dynamic-update-
+    slice, not a scatter: on the TPU a scatter into a bf16 pool changed the
+    NaN payloads of resident ciphertext, which is full of NaN bit patterns;
+    a dynamic-update-slice keeps every bit."""
+    return jax.lax.dynamic_update_slice_in_dim(
+        pool_leaf, page.astype(pool_leaf.dtype), phys, axis=1)
+
+
 @partial(jax.jit, donate_argnums=(0,))
 def _set_pages(pool_leaf, idx, pages):
-    """Scatter restored pages into a donated pool leaf in place — restore
+    """Write restored pages into a donated pool leaf in place — restore
     cost stays O(pages moved), not O(pool) rebuilt per leaf."""
-    return pool_leaf.at[:, idx].set(pages.astype(pool_leaf.dtype))
+    for n in range(pages.shape[1]):
+        pool_leaf = _write_page(pool_leaf, idx[n], pages[:, n:n + 1])
+    return pool_leaf
 
 
 @partial(jax.jit, donate_argnums=(0,))
@@ -232,18 +242,19 @@ class PagedKVBackend(KVBackend):
         self.num_pages = num_pages
         self._key_salt = f"{model.cfg.name}|{page_size}|{max_len}".encode()
 
-        # classify leaves once; paged leaves move to pool layout
-        dense = model.init_cache(max_slots, max_len)
+        # classify leaves once (from shapes: the dense cache is never
+        # allocated); paged leaves move to pool layout, the rest stay dense
+        dense = model.abstract_cache(max_slots, max_len)
         dense.pop("pos")
         self._paged_paths = set()
 
         def build(path, leaf):
+            shape = leaf.shape
             if (_leaf_key(path) in _LENGTH_LEAVES and leaf.ndim >= 3
-                    and leaf.shape[2] == max_len):
+                    and shape[2] == max_len):
                 self._paged_paths.add(_keystr(path))
-                shape = (leaf.shape[0], num_pages + 1, page_size) + leaf.shape[3:]
-                return jnp.zeros(shape, leaf.dtype)
-            return leaf
+                shape = (shape[0], num_pages + 1, page_size) + shape[3:]
+            return jnp.zeros(shape, leaf.dtype)
         self.blocks = jax.tree_util.tree_map_with_path(build, dense)
         if not self._paged_paths:
             raise ValueError(
@@ -361,8 +372,13 @@ class PagedKVBackend(KVBackend):
                         src[:, dense_rows].astype(pool.dtype))
                 pages = src.reshape(src.shape[0], src.shape[1],
                                     self.max_pages, page_size, *src.shape[3:])
-                picked = pages[:, page_rows, page_ord]   # [L, n, ps, ...]
-                return pool.at[:, phys].set(picked.astype(pool.dtype))
+                size = (pages.shape[0], 1, 1) + pages.shape[3:]
+                for n in range(phys.shape[0]):
+                    start = ((0, page_rows[n], page_ord[n])
+                             + (0,) * (pages.ndim - 3))
+                    page = jax.lax.dynamic_slice(pages, start, size)
+                    pool = _write_page(pool, phys[n], page[:, 0])
+                return pool
             return jax.tree_util.tree_map_with_path(upd, blocks, prefilled)
 
         self._splice_fn = self.plan.compile(_splice, donate_argnums=(0,))
@@ -371,7 +387,8 @@ class PagedKVBackend(KVBackend):
             def upd(path, pool):
                 if _keystr(path) not in paged:
                     return pool
-                return pool.at[:, dst].set(pool[:, src])
+                return _write_page(pool, dst, jax.lax.dynamic_slice_in_dim(
+                    pool, src, 1, axis=1))
             return jax.tree_util.tree_map_with_path(upd, blocks)
 
         self._copy_page_fn = self.plan.compile(_copy_page,
@@ -456,19 +473,21 @@ class PagedKVBackend(KVBackend):
                 vl = jax.lax.dynamic_index_in_dim(vp, li, 0, keepdims=False)
                 # append this step's k/v before attending (idle rows route
                 # to the null page), exactly like the gather path's
-                # write-then-attend
-                kl = kl.at[write_phys, write_off].set(
-                    k[:, 0].astype(kl.dtype))
-                vl = vl.at[write_phys, write_off].set(
-                    v[:, 0].astype(vl.dtype))
+                # write-then-attend; one dynamic-update-slice per row, not
+                # a scatter, so resident ciphertext keeps its bits (see
+                # _set_pages)
+                for i in range(k.shape[0]):
+                    at = (write_phys[i], write_off[i], 0, 0)
+                    kl = jax.lax.dynamic_update_slice(
+                        kl, k[i:i + 1].astype(kl.dtype), at)
+                    vl = jax.lax.dynamic_update_slice(
+                        vl, v[i:i + 1].astype(vl.dtype), at)
                 if use_cipher:
                     out = paged_attention_unseal(
                         q[:, 0], kl, vl, table, valid, li, key_words,
-                        k_crypt, v_crypt, blocks_per_page=bpp,
-                        interpret=INTERPRET)
+                        k_crypt, v_crypt, blocks_per_page=bpp)
                 else:
-                    out = paged_attention(q[:, 0], kl, vl, table, valid,
-                                          interpret=INTERPRET)
+                    out = paged_attention(q[:, 0], kl, vl, table, valid)
                 x = x + jnp.einsum("bshk,hkd->bsd",
                                    out.astype(q.dtype)[:, None],
                                    sl["attn"]["wo"])

@@ -20,8 +20,7 @@ Three plans:
     is a *trust* boundary the paper's Insight 9–12 cost model can account.
 
   * :class:`ShardedPlan` — one engine spans a ``jax`` mesh built from
-    :func:`repro.launch.mesh.make_host_mesh` (axes ``("data", "model")``,
-    via the :mod:`repro.distributed.compat` shims):
+    :func:`repro.launch.mesh.make_host_mesh` (axes ``("data", "model")``):
 
       - **batch** rows shard over the ``data`` axis (each device decodes
         ``max_slots / dp`` sequences);
@@ -46,7 +45,7 @@ Three plans:
     is lowered once more and its SPMD-partitioned HLO parsed with
     :func:`repro.roofline.analysis.parse_collectives` for the bytes each
     device moves per step, and an ``all_gather`` of that volume runs under
-    :func:`repro.distributed.compat.shard_map` on the real mesh to
+    ``jax.shard_map`` on the real mesh to
     *measure* the per-step collective time. Both flow into
     ``ChannelStats.collective_bytes`` / ``collective_s`` (per decode step),
     which ``overheads.predict(collective_s=...)`` accepts in place of its
@@ -189,9 +188,10 @@ class ShardedPlan(ComputePlan):
             if dp * tp > n:
                 raise ValueError(
                     f"mesh dp={dp},tp={tp} needs {dp * tp} devices but jax "
-                    f"sees {n}; set XLA_FLAGS=--xla_force_host_platform_"
-                    f"device_count={dp * tp} (before jax initializes) or "
-                    f"shrink the mesh")
+                    f"sees {n}: on an accelerator, run on a host with "
+                    f"{dp * tp} chips or shrink the mesh; on the CPU, set "
+                    f"XLA_FLAGS=--xla_force_host_platform_device_count="
+                    f"{dp * tp} before jax initializes")
             mesh = make_host_mesh(data=dp, model=tp)
         self.mesh = mesh
         self.dp = int(mesh.shape["data"])
@@ -218,11 +218,57 @@ class ShardedPlan(ComputePlan):
             lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
             tree, spec_tree, is_leaf=lambda x: isinstance(x, P))
 
-    def place_params(self, params: Params) -> Params:
+    def _param_specs(self):
         from repro.distributed import sharding
-        specs = sharding.param_specs(self._spec_cfg,
-                                     self.model.abstract_params(), self.mesh)
-        return self._put(params, specs)
+        return sharding.param_specs(self._spec_cfg,
+                                    self.model.abstract_params(), self.mesh)
+
+    def place_params(self, params: Params) -> Params:
+        return self._put(params, self._param_specs())
+
+    def _gather_at_use(self, fn):
+        """Wrap a step whose first argument is the params so that they are
+        all-gathered over ``data`` inside it (TP dims stay partitioned).
+        Every matmul then runs on whole weights, which keeps dp outputs
+        identical to the single-device plan. Left to itself, the partitioner
+        may keep FSDP weights sharded and all-reduce partial products, which
+        reorders the sums (it does so on TPU).
+
+        Stacked layer weights are gathered one layer at a time inside the
+        layer scan (:func:`repro.models.layers.layer_params_hook`), so a
+        device holds its FSDP shard plus one gathered layer, never the
+        whole model; the rest (embedding, final norms) is gathered at step
+        entry."""
+        from repro.distributed.sharding import STACKED
+        from repro.models.layers import layer_params_hook
+
+        def drop_data(spec, lead=0):
+            def axis(a):
+                if isinstance(a, tuple):
+                    a = tuple(x for x in a if x != "data")
+                    return a or None
+                return None if a == "data" else a
+            return NamedSharding(self.mesh,
+                                 P(*(axis(a) for a in tuple(spec)[lead:])))
+
+        is_spec = lambda x: isinstance(x, P)        # noqa: E731
+        specs = self._param_specs()
+        per_layer = {k: jax.tree.map(lambda s: drop_data(s, 1), v,
+                                     is_leaf=is_spec)
+                     for k, v in specs.items() if k in STACKED}
+        whole = {k: jax.tree.map(drop_data, v, is_leaf=is_spec)
+                 for k, v in specs.items() if k not in STACKED}
+
+        def gather_layer(block, lp):
+            return jax.lax.with_sharding_constraint(lp, per_layer[block])
+
+        def gathered(params, *args):
+            params = dict(params)
+            params.update(jax.lax.with_sharding_constraint(
+                {k: params[k] for k in whole}, whole))
+            with layer_params_hook(gather_layer):
+                return fn(params, *args)
+        return gathered
 
     def place_dense_cache(self, cache: Cache) -> Cache:
         from repro.distributed import sharding
@@ -257,7 +303,7 @@ class ShardedPlan(ComputePlan):
         def _prefill(params, tokens, cache):
             return model.prefill(params, {"tokens": tokens}, cache)
 
-        jitted = jax.jit(_prefill)
+        jitted = jax.jit(self._gather_at_use(_prefill))
 
         def run(params, tokens, cache):
             rows = tokens.shape[0]
@@ -276,7 +322,8 @@ class ShardedPlan(ComputePlan):
         return P(None, "data", *([None] * (leaf.ndim - 2)))
 
     def compile_decode(self, fn, *, donate_argnums=(), static_argnums=()):
-        jitted = jax.jit(fn, donate_argnums=donate_argnums,
+        jitted = jax.jit(self._gather_at_use(fn),
+                         donate_argnums=donate_argnums,
                          static_argnums=static_argnums)
         plan = self
 
@@ -296,19 +343,11 @@ class ShardedPlan(ComputePlan):
         volume's all-gather time on the real mesh. One extra compile, once
         per engine; later sampling variants share the calibration (their
         collective profile is the same param gather)."""
+        from repro.roofline.analysis import parse_collectives
         self._analyzed = True
-        try:
-            from repro.roofline.analysis import parse_collectives
-            hlo = jitted.lower(*args).compile().as_text()
-            ops = parse_collectives(hlo)
-            self.collective_bytes_per_step = int(
-                sum(op.moved_bytes for op in ops))
-        except Exception as e:  # pragma: no cover - AOT text is best-effort
-            # degrade loudly: a silent zero here would make the measured
-            # link-tax report claim "0 B/step" as if it were an observation.
-            print(f"[mesh] WARNING: collective HLO analysis failed ({e!r}); "
-                  f"collective_bytes/collective_s will read 0")
-            self.collective_bytes_per_step = 0
+        hlo = jitted.lower(*args).compile().as_text()
+        self.collective_bytes_per_step = int(
+            sum(op.moved_bytes for op in parse_collectives(hlo)))
         self.collective_s_per_step = self.measure_collective_s(
             self.collective_bytes_per_step)
 
@@ -319,7 +358,6 @@ class ShardedPlan(ComputePlan):
         decode step's gather traffic. Returns seconds per step."""
         if nbytes <= 0 or self.mesh.size < 2:
             return 0.0
-        from repro.distributed.compat import shard_map
         iters = iters or self.probe_iters
         n_dev = self.mesh.size
         axes = tuple(self.mesh.axis_names)
@@ -332,8 +370,8 @@ class ShardedPlan(ComputePlan):
         def gather(local):
             return jax.lax.all_gather(local, axes, axis=0, tiled=True)
 
-        f = jax.jit(shard_map(gather, mesh=self.mesh, in_specs=P(axes),
-                              out_specs=P(None), check_vma=False))
+        f = jax.jit(jax.shard_map(gather, mesh=self.mesh, in_specs=P(axes),
+                                  out_specs=P(None), check_vma=False))
         f(x).block_until_ready()           # compile outside the clock
         t0 = time.perf_counter()
         for _ in range(iters):

@@ -62,6 +62,50 @@ class TestSealing:
         c2 = seal_tensor(key, "b", x).ciphertext
         assert not bool(jnp.all(c1 == c2))
 
+    @pytest.mark.parametrize("dtype,shape", [
+        ("bfloat16", (64, 4096)), ("float32", (33, 512, 32)),
+        ("int8", (100, 2048)), ("bfloat16", (37, 8, 2048)),
+    ])
+    def test_chunked_device_seal_matches_whole(self, dtype, shape,
+                                               monkeypatch):
+        """A device tensor over CHUNK_BYTES seals and unseals a chunk of
+        rows at a time: same ciphertext and MAC as sealing its bytes whole
+        on the host, and a bit-exact round trip, with the ciphertext at
+        rest on the host."""
+        from repro.core import sealing
+        key = SealingKey.generate(b"chunks")
+        x = (jnp.arange(np.prod(shape), dtype=jnp.float32).reshape(shape)
+             * 0.37).astype(dtype)
+        whole = seal_tensor(key, "w", np.asarray(x))
+        monkeypatch.setattr(sealing, "CHUNK_BYTES", 64 * 1024)
+        assert len(sealing._row_chunks(shape, x.dtype.itemsize)) > 1
+        chunked = seal_tensor(key, "w", x)
+        assert isinstance(chunked.ciphertext, np.ndarray)
+        assert np.array_equal(chunked.ciphertext, whole.ciphertext)
+        assert chunked.mac == whole.mac
+        back = unseal_tensor(key, chunked)
+        assert back.dtype == x.dtype and back.shape == x.shape
+        assert np.array_equal(np.asarray(back).view(np.uint8),
+                              np.asarray(x).view(np.uint8))
+
+    @pytest.mark.parametrize("dtype,shape", [
+        ("float32", ()), ("int32", (3,)), ("bfloat16", (5, 7)),
+        ("uint8", (130,)),
+    ])
+    def test_small_device_seal_matches_host(self, dtype, shape):
+        """A small device tensor (one chunk, or a scalar) seals to the same
+        bytes as its host copy, and both unseal bit-exactly."""
+        key = SealingKey.generate(b"small")
+        x = (jnp.arange(int(np.prod(shape)), dtype=jnp.float32)
+             .reshape(shape) * 3.1).astype(dtype)
+        dev, host = (seal_tensor(key, "t", a) for a in (x, np.asarray(x)))
+        assert np.array_equal(dev.ciphertext, host.ciphertext)
+        assert dev.mac == host.mac
+        for st_ in (dev, host):
+            back = unseal_tensor(key, st_)
+            assert back.dtype == x.dtype and back.shape == x.shape
+            assert np.asarray(back).tobytes() == np.asarray(x).tobytes()
+
     @given(seed=st.integers(0, 2**31 - 1), n=st.integers(1, 2000))
     @settings(max_examples=10, deadline=None)
     def test_roundtrip_property(self, seed, n):

@@ -58,7 +58,7 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import smoke_config
 from repro.models import build_model
 from repro.distributed import sharding
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 for name in ["deepseek-7b", "qwen3-32b", "rwkv6-3b", "dbrx-132b",
              "deepseek-v3-671b", "jamba-v0.1-52b", "chameleon-34b",
@@ -81,7 +81,7 @@ from repro.models import build_model
 from repro.distributed import sharding
 from repro.train.optimizer import AdamWConfig
 from repro.train.train_loop import init_train_state, make_train_step, abstract_train_state
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((2, 4), ("data", "model"))
 cfg = smoke_config("qwen3-32b")
 m = build_model(cfg)
@@ -112,7 +112,8 @@ class TestOverlap:
         out = subproc("""
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.distributed.compat import make_mesh, shard_map
+from repro.launch.mesh import make_mesh
+from jax import shard_map
 from repro.distributed.overlap import all_gather_matmul, matmul_reduce_scatter
 mesh = make_mesh((8,), ("model",))
 x = jax.random.normal(jax.random.key(1), (64, 32))
@@ -140,7 +141,7 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.distributed.pipeline import make_gpipe
 S, d = 4, 16
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh = make_mesh((4, 2), ("pipe", "data"))
 ws = jax.random.normal(jax.random.key(5), (S, d, d)) * 0.3
 stage = lambda w, x: jnp.tanh(x @ w)
@@ -174,7 +175,7 @@ from repro.train.checkpoint import save_checkpoint, restore_checkpoint
 cfg = smoke_config("deepseek-7b")
 m = build_model(cfg)
 params = m.init_params(jax.random.key(0))
-from repro.distributed.compat import make_mesh
+from repro.launch.mesh import make_mesh
 mesh_a = make_mesh((2, 4), ("data", "model"))
 specs_a = sharding.to_named(mesh_a, sharding.param_specs(cfg, m.abstract_params(), mesh_a))
 pa = jax.tree.map(jax.device_put, params, specs_a)

@@ -1,5 +1,6 @@
 """Table-walking Pallas paged-attention decode kernel + fused in-kernel
-unseal: kernel-level parity against a dense-gather oracle, fused-decrypt
+unseal: kernel-level parity against the dense-gather reference
+(kernels/ref.py), fused-decrypt
 parity against unseal-then-attend, backend/engine wiring, and the
 ciphertext-resident restore lifecycle (MAC gate, materialization on host
 consumption, decoded-token equality with the gather reference)."""
@@ -14,36 +15,12 @@ from repro.core.sealing import (IntegrityError, SealingKey, seal_tensor,
 from repro.kernels.paged_attention import (paged_attention,
                                            paged_attention_unseal,
                                            supports_fused_unseal)
+from repro.kernels.ref import paged_attention_ref
 
 
 # ---------------------------------------------------------------------------
-# oracles and fixtures
+# fixtures
 # ---------------------------------------------------------------------------
-
-def dense_oracle(q, k_pool, v_pool, table, valid):
-    """Gather the pages dense, run masked GQA softmax attention in f64-free
-    numpy — the same math the gather decode path's sdpa performs."""
-    b, h, hd = q.shape
-    _, ps, hk, _ = k_pool.shape
-    g = h // hk
-    out = np.zeros((b, h, hd), np.float32)
-    for i in range(b):
-        n = int(valid[i])
-        if n == 0:
-            continue
-        phys = np.asarray(table[i])
-        k = np.concatenate([np.asarray(k_pool[p]) for p in phys])[:n]
-        v = np.concatenate([np.asarray(v_pool[p]) for p in phys])[:n]
-        qg = np.asarray(q[i], np.float32).reshape(hk, g, hd)
-        kf = k.astype(np.float32)                       # [n, hk, hd]
-        s = np.einsum("kgd,nkd->kgn", qg, kf) / np.sqrt(hd)
-        s = s - s.max(axis=-1, keepdims=True)
-        p = np.exp(s)
-        p /= p.sum(axis=-1, keepdims=True)
-        out[i] = np.einsum("kgn,nkd->kgd",
-                           p, v.astype(np.float32)).reshape(h, hd)
-    return out
-
 
 def make_pool(rng, *, slots=3, pages=4, ps=8, h=4, hk=2, hd=16,
               dtype=np.float32):
@@ -68,7 +45,7 @@ class TestPagedAttentionKernel:
         out = paged_attention(jnp.asarray(q), jnp.asarray(kp),
                               jnp.asarray(vp), jnp.asarray(table),
                               jnp.asarray(valid))
-        expect = dense_oracle(q, kp, vp, table, valid)
+        expect = paged_attention_ref(q, kp, vp, table, valid)
         live = valid > 0
         np.testing.assert_allclose(np.asarray(out)[live], expect[live],
                                    atol=2e-5, rtol=2e-5)
@@ -97,10 +74,10 @@ class TestPagedAttentionKernel:
         to16 = lambda a: jnp.asarray(a).astype(jnp.bfloat16)
         out = paged_attention(to16(q), to16(kp), to16(vp),
                               jnp.asarray(table), jnp.asarray(valid))
-        expect = dense_oracle(np.asarray(to16(q), np.float32),
-                              np.asarray(to16(kp), np.float32),
-                              np.asarray(to16(vp), np.float32),
-                              table, valid)
+        expect = paged_attention_ref(np.asarray(to16(q), np.float32),
+                                     np.asarray(to16(kp), np.float32),
+                                     np.asarray(to16(vp), np.float32),
+                                     table, valid)
         live = valid > 0
         np.testing.assert_allclose(
             np.asarray(out, np.float32)[live], expect[live], atol=3e-2)
@@ -225,6 +202,46 @@ class TestFusedUnseal:
                                   jnp.asarray(valid))
             np.testing.assert_array_equal(np.asarray(fused),
                                           np.asarray(ref))
+
+    @pytest.mark.parametrize("ps,hk,hd,dtype", [
+        (8, 12, 128, np.float32),    # a page row spans two keystream rows
+        (8, 4, 128, np.float32),     # four page rows share one
+    ])
+    def test_multi_row_keystream(self, ps, hk, hd, dtype):
+        """Pages of more than 128 ChaCha20 blocks: the kernel computes the
+        page's keystream once, block index on the lanes, and gathers each
+        row's words from it. Kernel and emulation both decrypt every
+        layer's ciphertext to exactly what the plaintext pool attends."""
+        rng = np.random.default_rng(5)
+        L, h = 2, hk
+        q, kp, vp, table, valid = make_pool(
+            rng, slots=1, pages=2, ps=ps, h=h, hk=hk, hd=hd)
+        q, kp, vp = (np.asarray(jnp.asarray(a).astype(dtype))
+                     for a in (q, kp, vp))
+        bpp = ps * hk * hd * jnp.dtype(dtype).itemsize // 64
+        assert bpp > 128
+        key = SealingKey.generate(b"rows")
+        phys = int(table[0, 1])
+        stacked = np.asarray(jnp.asarray(
+            rng.normal(size=(L, ps, hk, hd))).astype(dtype))
+        _, bits, nonce = seal_page_linear(key, "t['v']/p1", stacked)
+        crypt = np.zeros((kp.shape[0], 4), np.uint32)
+        crypt[phys, :3], crypt[phys, 3] = nonce, 1
+        for l in range(L):
+            vp_c, vp_p = vp.copy(), vp.copy()
+            vp_c[phys], vp_p[phys] = bits[l], stacked[l]
+            ref = paged_attention(jnp.asarray(q), jnp.asarray(kp),
+                                  jnp.asarray(vp_p), jnp.asarray(table),
+                                  jnp.asarray(valid))
+            for emulate in (True, False):
+                fused = paged_attention_unseal(
+                    jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp_c),
+                    jnp.asarray(table), jnp.asarray(valid), jnp.int32(l),
+                    key.key_words, jnp.asarray(np.zeros_like(crypt)),
+                    jnp.asarray(crypt), blocks_per_page=bpp,
+                    emulate=emulate)
+                np.testing.assert_array_equal(np.asarray(fused),
+                                              np.asarray(ref))
 
     def test_eligibility_predicate(self):
         assert supports_fused_unseal(jnp.float32, 8192)

@@ -85,6 +85,66 @@ class TestPlanPlumbing:
         with pytest.raises(ValueError, match="xla_force_host_platform"):
             make_engine(model, params, mesh="dp=4096")
 
+    def test_accelerator_short_of_devices_raises_without_relaunch(
+            self, monkeypatch):
+        """Off the CPU the process holds its chips: too few devices is an
+        error, never a child process."""
+        from types import SimpleNamespace
+        from repro.launch import mesh
+        monkeypatch.setattr(mesh.jax, "devices",
+                            lambda: [SimpleNamespace(platform="tpu")])
+        monkeypatch.setattr(mesh.subprocess, "run", lambda *a, **k: 1 / 0)
+        with pytest.raises(RuntimeError, match="4 chips"):
+            mesh.ensure_host_devices(4)
+        mesh.ensure_host_devices(1)            # enough devices: no-op
+
+    def test_params_gathered_one_layer_at_a_time(self, small_model):
+        """The gather-at-use constraints on stacked layer weights sit inside
+        the layer scan, on one layer's slice: a dp device never holds the
+        whole gathered model. Only unstacked leaves are gathered at entry."""
+        cfg, model, params = small_model
+        plan = ShardedPlan(model, dp=1)
+        step = plan._gather_at_use(
+            lambda p, t, c: model.prefill(p, {"tokens": t}, c))
+        jaxpr = jax.make_jaxpr(step)(
+            params, np.ones((2, 8), np.int32), model.init_cache(2, 16))
+
+        def constrained(eqns, nested=True):
+            shapes = []
+            for e in eqns:
+                if e.primitive.name == "sharding_constraint":
+                    shapes += [v.aval.shape for v in e.invars]
+                elif nested:
+                    for sub in e.params.values():
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            shapes += constrained(sub.eqns)
+            return shapes
+
+        entry = constrained(jaxpr.eqns, nested=False)
+        scan = next(e for e in jaxpr.eqns if e.primitive.name == "scan")
+        in_scan = constrained(scan.params["jaxpr"].eqns)
+        stacked = jax.tree.leaves(params["layers"])
+        assert sorted(in_scan) == sorted(x.shape[1:] for x in stacked)
+        assert len(entry) == len(jax.tree.leaves(params)) - len(stacked)
+        assert not {x.shape for x in stacked} & set(entry)
+
+    def test_collective_analysis_failure_raises(self, small_model,
+                                                monkeypatch):
+        """A failed HLO collective analysis stops the engine; it must not
+        report 0 collective bytes as if it had measured them."""
+        from repro.roofline import analysis
+        cfg, model, params = small_model
+
+        def broken(hlo):
+            raise ValueError("unparseable HLO")
+        monkeypatch.setattr(analysis, "parse_collectives", broken)
+        eng = make_engine(model, params, mesh="dp=1")
+        eng.submit(GenerationRequest(prompt=np.arange(1, 5, dtype=np.int32),
+                                     max_new_tokens=2))
+        with pytest.raises(ValueError, match="unparseable"):
+            eng.run(max_steps=10)
+
 
 class TestOneDeviceMeshParity:
     """Sharded-vs-single output parity moved into the differential harness
