@@ -17,8 +17,9 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.sealing import (IntegrityError, SealingKey, SealedTensor,
-                                seal_tensor, unseal_tensor)
+                                seal_tensor, unseal_to_host)
 
 
 @dataclasses.dataclass
@@ -27,11 +28,15 @@ class ChannelStats:
     (frames — the unit Insight 10's fixed cost is paid per); ``tokens_out``
     counts the tokens those frames carried. With per-token streaming the two
     are equal; a coalescing FramePolicy drives messages_out/tokens_out
-    toward 1/N, which is exactly the amortization curve serve_bench plots."""
+    toward 1/N, which is exactly the amortization curve serve_bench plots.
+    ``bytes_*`` count the plaintext; ``cipher_bytes_*`` the padded
+    ciphertext each message moved and MAC'd (whole ChaCha20 tiles)."""
     messages_in: int = 0
     messages_out: int = 0
     bytes_in: int = 0
     bytes_out: int = 0
+    cipher_bytes_in: int = 0
+    cipher_bytes_out: int = 0
     tokens_out: int = 0
     # sealed-KV traffic (preemption evictions/restores): ciphertext that
     # leaves/re-enters the domain outside the token channel. Counted apart
@@ -74,6 +79,7 @@ class ChannelStats:
     def reset(self):
         self.messages_in = self.messages_out = 0
         self.bytes_in = self.bytes_out = 0
+        self.cipher_bytes_in = self.cipher_bytes_out = 0
         self.tokens_out = 0
         self.seal_events = self.seal_bytes = 0
         self.restore_events = self.restore_bytes = 0
@@ -127,13 +133,16 @@ class BounceBuffer:
     def host_send(self, tokens: np.ndarray) -> SealedTensor:
         name = f"ingress/{self._seq_in}"
         self._seq_in += 1
-        sealed = seal_tensor(self.key, name, tokens)
+        with obs.span("ingress.seal"):
+            sealed = seal_tensor(self.key, name, tokens)
         self.stats.messages_in += 1
         self.stats.bytes_in += sealed.n_bytes
+        self.stats.cipher_bytes_in += sealed.ciphertext.nbytes
         return sealed
 
     def device_recv(self, sealed: SealedTensor) -> np.ndarray:
-        return np.asarray(unseal_tensor(self.key, sealed))
+        with obs.span("ingress.open"):
+            return unseal_to_host(self.key, sealed)
 
     # device -> host
     def device_send(self, tokens: np.ndarray) -> SealedTensor:
@@ -142,10 +151,11 @@ class BounceBuffer:
         sealed = seal_tensor(self.key, name, tokens)
         self.stats.messages_out += 1
         self.stats.bytes_out += sealed.n_bytes
+        self.stats.cipher_bytes_out += sealed.ciphertext.nbytes
         return sealed
 
     def host_recv(self, sealed: SealedTensor) -> np.ndarray:
-        return np.asarray(unseal_tensor(self.key, sealed))
+        return unseal_to_host(self.key, sealed)
 
     def open_stream(self) -> int:
         """Allocate a channel-global stream id. The channel — not the caller —
@@ -169,13 +179,19 @@ class BounceBuffer:
         seq = self._stream_seq.get(stream_id, 0)
         self._stream_seq[stream_id] = seq + 1
         name = TokenFrame.frame_name(stream_id, seq)
-        sealed = seal_tensor(self.key, name, tokens)
+        with obs.span("frame.seal", stream=stream_id):
+            sealed = seal_tensor(self.key, name, tokens)
         self.stats.messages_out += 1
         self.stats.bytes_out += sealed.n_bytes
+        self.stats.cipher_bytes_out += sealed.ciphertext.nbytes
         self.stats.tokens_out += int(tokens.size)
         return TokenFrame(stream_id, seq, sealed)
 
     def host_recv_frame(self, frame: TokenFrame) -> np.ndarray:
+        with obs.span("frame.open", stream=frame.stream_id):
+            return self._open_frame(frame)
+
+    def _open_frame(self, frame: TokenFrame) -> np.ndarray:
         if self._stream_closed(frame.stream_id):
             raise IntegrityError(
                 f"stream {frame.stream_id} is closed "
@@ -193,7 +209,7 @@ class BounceBuffer:
             raise IntegrityError(
                 f"stream {frame.stream_id}: got frame seq {frame.seq}, "
                 f"expected {want} (replayed or reordered frame)")
-        out = np.asarray(unseal_tensor(self.key, frame.sealed))
+        out = unseal_to_host(self.key, frame.sealed)
         # advance only after the MAC verified: a forged frame must not burn
         # the seq and lock out the authentic one behind it.
         self._stream_recv[frame.stream_id] = want + 1

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import ops
 from repro.kernels.chacha20 import BLOCKS_PER_TILE
 
@@ -144,26 +145,29 @@ def _row_chunks(shape: Tuple[int, ...], itemsize: int):
 
 def seal_tensor(key: SealingKey, name: str, array) -> SealedTensor:
     nonce = jnp.asarray(np.frombuffer(_nonce_for(key, name), np.uint32))
-    if isinstance(array, jax.Array):
-        rows = array.reshape(_rows_shape(array.shape))
-        n_bytes = array.nbytes
-        blocks = -(-n_bytes // (64 * BLOCKS_PER_TILE)) * BLOCKS_PER_TILE
-        ciphertext = np.empty((16, blocks), np.uint32)
-        for start, n, c0 in _row_chunks(rows.shape, array.dtype.itemsize):
-            ct = np.asarray(ops.seal_u32(key.key_words, nonce,
-                                         ops.pack_rows(rows, start, n),
-                                         counter_base=c0))
-            ciphertext[:, c0:c0 + ct.shape[1]] = ct
-        shape, dtype = array.shape, array.dtype
-    else:
-        arr = np.asarray(array)
-        raw = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
-        blocked, n_bytes = ops.pack_u32(raw)
-        ciphertext = np.asarray(ops.seal_u32(key.key_words, nonce, blocked))
-        shape, dtype = arr.shape, arr.dtype
+    with obs.span("seal.kernel"):
+        if isinstance(array, jax.Array):
+            rows = array.reshape(_rows_shape(array.shape))
+            n_bytes = array.nbytes
+            blocks = -(-n_bytes // (64 * BLOCKS_PER_TILE)) * BLOCKS_PER_TILE
+            ciphertext = np.empty((16, blocks), np.uint32)
+            for start, n, c0 in _row_chunks(rows.shape, array.dtype.itemsize):
+                ct = np.asarray(ops.seal_u32(key.key_words, nonce,
+                                             ops.pack_rows(rows, start, n),
+                                             counter_base=c0))
+                ciphertext[:, c0:c0 + ct.shape[1]] = ct
+            shape, dtype = array.shape, array.dtype
+        else:
+            arr = np.asarray(array)
+            raw = np.ascontiguousarray(arr).reshape(-1).view(np.uint8)
+            blocked, n_bytes = ops.pack_u32(raw)
+            ciphertext = np.asarray(ops.seal_u32(key.key_words, nonce,
+                                                 blocked))
+            shape, dtype = arr.shape, arr.dtype
     st = SealedTensor(name=name, ciphertext=ciphertext, mac=b"",
                       shape=tuple(shape), dtype=str(dtype), n_bytes=n_bytes)
-    st.mac = _mac(key, st.header(), ciphertext)
+    with obs.span("seal.mac"):
+        st.mac = _mac(key, st.header(), ciphertext)
     return st
 
 
@@ -176,7 +180,8 @@ def verify_mac(key: SealingKey, sealed: SealedTensor) -> None:
     integrity gate that must pass before any kernel consumes the bits.
     Raises :class:`IntegrityError` on mismatch, like unseal_tensor.
     """
-    expect = _mac(key, sealed.header(), sealed.ciphertext)
+    with obs.span("unseal.verify"):
+        expect = _mac(key, sealed.header(), sealed.ciphertext)
     if not hmac.compare_digest(expect, sealed.mac):
         raise IntegrityError(f"MAC mismatch for tensor '{sealed.name}'")
 
@@ -196,6 +201,19 @@ def ciphertext_page_bytes(sealed: SealedTensor) -> bytes:
 def unseal_tensor(key: SealingKey, sealed: SealedTensor) -> jax.Array:
     """MAC-check, then decrypt onto the device."""
     verify_mac(key, sealed)
+    with obs.span("unseal.kernel"):
+        return _decrypt(key, sealed)
+
+
+def unseal_to_host(key: SealingKey, sealed: SealedTensor) -> np.ndarray:
+    """MAC-check, decrypt on the device and read the result back (the read
+    inside the ``unseal.kernel`` span, which then holds the device's time)."""
+    verify_mac(key, sealed)
+    with obs.span("unseal.kernel"):
+        return np.asarray(_decrypt(key, sealed))
+
+
+def _decrypt(key: SealingKey, sealed: SealedTensor) -> jax.Array:
     nonce = jnp.asarray(np.frombuffer(_nonce_for(key, sealed.name), np.uint32))
     dtype = jnp.dtype(sealed.dtype)
     shape = _rows_shape(sealed.shape)

@@ -94,6 +94,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.confidential import TrustDomain
 from repro.core.sealing import (IntegrityError, seal_tree, sealed_nbytes,
                                 unseal_tree)
@@ -803,22 +804,35 @@ class Engine:
         # bounded: |buckets| x log2(max_slots) variants, not one per batch.
         rows = next_pow2(len(group))
         tokens = np.zeros((rows, bucket), np.int32)
+        pad = 0
         for i, req in enumerate(group):
             chunk = req.prompt[:bucket]
             tokens[i, bucket - len(chunk):] = chunk   # left-pad short prompts
+            pad += bucket - len(chunk)
+        self._count_prefill(len(group), rows, pad)
         fresh = self.kv.fresh_prefill_cache(rows)
-        logits, prefilled = self._prefill_fn(self.params, host_upload(tokens),
-                                             fresh)
-        first_np = self._first_tokens(logits, group, rows)
+        with obs.span("step.prefill", rows=rows, bucket=bucket):
+            logits, prefilled = self._prefill_fn(
+                self.params, host_upload(tokens), fresh)
+            first_np = self._first_tokens(logits, group, rows)
 
         group_keys = None
         if self.kv.supports_sharing:
             group_keys = [req.page_keys for req in group]
-        self.kv.insert_prefill(prefilled, slots, bucket,
-                               page_keys=group_keys)
+        with obs.span("step.insert"):
+            self.kv.insert_prefill(prefilled, slots, bucket,
+                                   page_keys=group_keys)
         for i, req in enumerate(group):
             self._start_decode(slots[i], req, int(first_np[i]), bucket)
         return len(group)
+
+    @staticmethod
+    def _count_prefill(real_rows: int, rows: int, pad_tokens: int) -> None:
+        """Prefill rows, the rows added to reach a power of two, and the
+        tokens of left padding up to the bucket."""
+        obs.count("engine.prefill_rows", real_rows)
+        obs.count("engine.prefill_pad_rows", rows - real_rows)
+        obs.count("engine.prefill_pad_tokens", pad_tokens)
 
     def _start_decode(self, slot: int, req: Request, first_tok: int,
                       bucket: int) -> None:
@@ -882,23 +896,27 @@ class Engine:
         tokens = np.zeros((1, bucket), np.int32)
         chunk = req.prompt[:bucket]
         tokens[0, bucket - len(chunk):] = chunk   # left-pad short prompts
+        self._count_prefill(1, 1, bucket - len(chunk))
         if self._prefill_stream_fn is not None:
             # jax dispatches this call asynchronously: the decode step the
             # engine runs next overlaps with it, and _handoff_ready blocks
             # on the result only when it crosses to the decode plan.
             fresh = self.model.init_cache(1, self.max_len)
-            logits, cache = self._prefill_stream_fn(
-                self.prefill_params, host_upload(tokens), fresh)
+            with obs.span("step.prefill", rows=1, bucket=bucket):
+                logits, cache = self._prefill_stream_fn(
+                    self.prefill_params, host_upload(tokens), fresh)
             req.phase = "prefill"
             self._inflight[slot] = InflightPrefill(req, slot, bucket,
                                                    logits, cache)
             return
         fresh = self.kv.fresh_prefill_cache(1)
-        logits, prefilled = self._prefill_fn(self.params, host_upload(tokens),
-                                             fresh)
-        first_np = self._first_tokens(logits, [req], 1)
+        with obs.span("step.prefill", rows=1, bucket=bucket):
+            logits, prefilled = self._prefill_fn(
+                self.params, host_upload(tokens), fresh)
+            first_np = self._first_tokens(logits, [req], 1)
         keys = [req.page_keys] if self.kv.supports_sharing else None
-        self.kv.insert_prefill(prefilled, [slot], bucket, page_keys=keys)
+        with obs.span("step.insert"):
+            self.kv.insert_prefill(prefilled, [slot], bucket, page_keys=keys)
         self._start_decode(slot, req, int(first_np[0]), bucket)
 
     def _admit_continuous(self) -> int:
@@ -1008,7 +1026,9 @@ class Engine:
                                    self.model.abstract_cache(1, self.max_len),
                                    prefix=prefix)
             keys = [req.page_keys] if self.kv.supports_sharing else None
-            self.kv.insert_prefill(restored, [slot], bucket, page_keys=keys)
+            with obs.span("step.insert"):
+                self.kv.insert_prefill(restored, [slot], bucket,
+                                       page_keys=keys)
             first_np = self._first_tokens(inf.logits, [req], 1)
             self._start_decode(slot, req, int(first_np[0]), bucket)
 
@@ -1140,17 +1160,20 @@ class Engine:
         references remain — the page crosses out once, content-named),
         re-materialization (the first restore that needed it brings it
         back), and the persistent store's publish/hit/evict traffic."""
-        for kind, nb, n in self.kv.drain_events():
-            if kind == "park":
-                self.td.record_seal(nb, n, "shared page parked (last ref)")
-            elif kind == "store_publish":
-                self.td.record_seal(nb, n, "page published to sealed store")
-            elif kind == "store_hit":
-                self.td.record_store_hit(nb, n)
-            elif kind == "store_evict":
-                self.td.record_store_evict(nb, n)
-            else:
-                self.td.record_restore(nb, n, "shared page rematerialized")
+        with obs.span("step.kv_events"):
+            for kind, nb, n in self.kv.drain_events():
+                if kind == "park":
+                    self.td.record_seal(nb, n, "shared page parked (last ref)")
+                elif kind == "store_publish":
+                    self.td.record_seal(nb, n,
+                                        "page published to sealed store")
+                elif kind == "store_hit":
+                    self.td.record_store_hit(nb, n)
+                elif kind == "store_evict":
+                    self.td.record_store_evict(nb, n)
+                else:
+                    self.td.record_restore(nb, n,
+                                           "shared page rematerialized")
 
     def _grant_step_pages(self, live: List[int]) -> List[int]:
         """On-demand allocation: make sure the pool can grant every live
@@ -1198,6 +1221,11 @@ class Engine:
         admission/restoration/preemption, then one batched decode step.
         Returns number of *output* tokens produced (prompt-chunk feeding
         steps count zero)."""
+        obs.count("engine.steps")
+        with obs.span("step"):
+            return self._step()
+
+    def _step(self) -> int:
         t0 = time.monotonic()
         if self._inflight:
             self._handoff_ready()
@@ -1208,7 +1236,8 @@ class Engine:
             live_now = sum(1 for s in self.slots.active
                            if s not in self._paused)
             self._budget_left = max(0, self._step_tokens - live_now)
-        self._admit_ready()
+        with obs.span("step.admit"):
+            self._admit_ready()
         live = [s for s in self.slots.active
                 if s not in self._paused and s not in self._inflight]
         if live and self.kv.on_demand:
@@ -1226,9 +1255,12 @@ class Engine:
             if req.pending_input:
                 self._last_token[slot] = req.pending_input.pop(0)
                 feeding_prompt[slot] = bool(req.pending_input)
-        state, kmax = self._sampling_state(steps)
-        next_np = self.kv.decode(self.params, self._last_token, state, kmax,
-                                 write_slots=live)
+        with obs.span("step.sampling"):
+            state, kmax = self._sampling_state(steps)
+        with obs.span("step.decode"):
+            next_np = self.kv.decode(self.params, self._last_token, state,
+                                     kmax, write_slots=live)
+        obs.count("engine.rows_decoded", len(live))
         if self.plan.is_sharded:
             # account the step's cross-device collective traffic (bytes from
             # the compiled HLO, seconds from the plan's measured probe)
@@ -1236,13 +1268,14 @@ class Engine:
             if n:
                 self.td.record_collective(cb, cs, steps=n)
         produced = 0
-        for slot in list(live):
-            if not self._active_mask[slot] or slot in self._paused:
-                continue
-            if feeding_prompt.get(slot, False):
-                continue   # mid-prompt chunk: this step's sample is discarded
-            self._emit_token(slot, int(next_np[slot]))
-            produced += 1
+        with obs.span("step.emit"):
+            for slot in list(live):
+                if not self._active_mask[slot] or slot in self._paused:
+                    continue
+                if feeding_prompt.get(slot, False):
+                    continue   # mid-prompt chunk: its sample is discarded
+                self._emit_token(slot, int(next_np[slot]))
+                produced += 1
         self._drain_kv_events()
         # feasibility floor: only steps that actually decoded count (an
         # empty tick would fake an impossibly fast step and over-reject)
@@ -1310,38 +1343,41 @@ class Engine:
         overriding the key must not have a paused tail on the slot — that
         earlier blob is under THIS worker's key and cannot cross
         (``export_sealed_state`` reunites it first)."""
-        paused = self._paused.pop(slot, None)
-        req = self.scheduler.running.pop(slot)
-        assert paused is None or key is None, \
-            "cannot migration-seal a paused slot: its tail blob is local"
-        # a key override means the blob leaves this worker: shared pages
-        # must seal by VALUE (detach) — a by-reference entry resolves
-        # against THIS pool's content index / parked blobs, which the
-        # destination does not have
-        detach = key is not None and getattr(self.kv, "supports_sharing",
-                                             False)
-        key = key if key is not None else self.td.sealing_key
-        prefix = prefix if prefix is not None else self._seal_prefix(req)
-        if self.kv.supports_partial:
-            # what an on-demand restore must find free: the resident pages
-            # plus any earlier-sealed tail riding along (shared pages may
-            # re-link for less — this is the conservative bound).
-            req.sealed_pages = (self.kv.allocated_pages(slot)
-                                + (paused.n_pages if paused else 0))
-        sealed = (self.kv.seal(key, slot, prefix, detach=True) if detach
-                  else self.kv.seal(key, slot, prefix))
-        req.seal_epoch += 1
-        nb = sealed_nbytes(sealed)   # the paused tail was recorded at its seal
-        req.sealed_bytes += nb
-        self.td.record_seal(nb, len(sealed),
-                            f"slot={slot} rid={req.rid} stream={req.stream_id} "
-                            f"epoch={req.seal_epoch - 1}")
-        if paused is not None:
-            sealed.update(paused.sealed)
-        self.kv.release(slot)
-        self._active_mask[slot] = False
-        self._drain_kv_events()
-        return sealed, req
+        with obs.span("kv.seal_slot"):
+            paused = self._paused.pop(slot, None)
+            req = self.scheduler.running.pop(slot)
+            assert paused is None or key is None, \
+                "cannot migration-seal a paused slot: its tail blob is local"
+            # a key override means the blob leaves this worker: shared pages
+            # must seal by VALUE (detach) — a by-reference entry resolves
+            # against THIS pool's content index / parked blobs, which the
+            # destination does not have
+            detach = key is not None and getattr(self.kv, "supports_sharing",
+                                                 False)
+            key = key if key is not None else self.td.sealing_key
+            prefix = prefix if prefix is not None else self._seal_prefix(req)
+            if self.kv.supports_partial:
+                # what an on-demand restore must find free: the resident pages
+                # plus any earlier-sealed tail riding along (shared pages may
+                # re-link for less — this is the conservative bound).
+                req.sealed_pages = (self.kv.allocated_pages(slot)
+                                    + (paused.n_pages if paused else 0))
+            sealed = (self.kv.seal(key, slot, prefix, detach=True) if detach
+                      else self.kv.seal(key, slot, prefix))
+            req.seal_epoch += 1
+            # the paused tail was recorded at its own seal
+            nb = sealed_nbytes(sealed)
+            req.sealed_bytes += nb
+            self.td.record_seal(nb, len(sealed),
+                                f"slot={slot} rid={req.rid} "
+                                f"stream={req.stream_id} "
+                                f"epoch={req.seal_epoch - 1}")
+            if paused is not None:
+                sealed.update(paused.sealed)
+            self.kv.release(slot)
+            self._active_mask[slot] = False
+            self._drain_kv_events()
+            return sealed, req
 
     def restore_slot(self, sealed, req: Request, *, key=None,
                      prefix: Optional[str] = None) -> int:
@@ -1351,40 +1387,42 @@ class Engine:
         effective worst case. ``key``/``prefix`` override the worker
         defaults when the blob is a cross-worker migrant (sealed under a
         fleet-shared tenant domain in a ``kvmigrate/`` namespace)."""
-        slot = self.kv.acquire(req.rid,
-                               0 if self.kv.on_demand else req.kv_need)
-        if slot is None:
-            raise RuntimeError("no free slot/KV room to restore into")
-        key = key if key is not None else self.td.sealing_key
-        if prefix is None:
-            prefix = f"kvslot/{req.stream_id}/{req.seal_epoch - 1}"
-        try:
-            self.kv.restore(key, sealed, slot, prefix, req.kv_need)
-            # a sealed-while-paused eviction carries its earlier tail blob
-            # under an older epoch prefix (and, under a mesh, shard suffix);
-            # graft it back on top of the remainder (acquire() above already
-            # reserved the full need).
-            for gprefix, gsuffix in tail_blob_names(sealed):
-                self.kv.restore_tail_pages(
-                    key, sealed, slot, gprefix,
-                    reserve=False, suffix=gsuffix)
-        except Exception:
-            self.kv.release(slot)   # a failed (e.g. tampered) restore must
-            raise                   # not leak the slot or its reservation
-        # the WHOLE restore succeeded: only now are this sealed dict's
-        # shared-page references spent (a rolled-back restore must leave
-        # _sealed_refs and parked ciphertext intact for co-sharers)
-        self.kv.discard_sealed(key, sealed, prefix)
-        self.scheduler.running[slot] = req
-        self._active_mask[slot] = True
-        self._set_slot_sampling(slot, req)
-        # next decode input: the prompt tail (if chunked prefill was cut
-        # short) takes precedence in step(); otherwise the last output token.
-        self._last_token[slot] = req.output[-1] if req.output else 0
-        self.td.record_restore(sealed_nbytes(sealed), len(sealed),
-                               f"slot={slot} rid={req.rid}")
-        self._drain_kv_events()
-        return slot
+        with obs.span("kv.restore_slot"):
+            slot = self.kv.acquire(req.rid,
+                                   0 if self.kv.on_demand else req.kv_need)
+            if slot is None:
+                raise RuntimeError("no free slot/KV room to restore into")
+            key = key if key is not None else self.td.sealing_key
+            if prefix is None:
+                prefix = f"kvslot/{req.stream_id}/{req.seal_epoch - 1}"
+            try:
+                self.kv.restore(key, sealed, slot, prefix, req.kv_need)
+                # a sealed-while-paused eviction carries its earlier tail
+                # blob under an older epoch prefix (and, under a mesh, shard
+                # suffix); graft it back on top of the remainder (acquire()
+                # above already reserved the full need).
+                for gprefix, gsuffix in tail_blob_names(sealed):
+                    self.kv.restore_tail_pages(
+                        key, sealed, slot, gprefix,
+                        reserve=False, suffix=gsuffix)
+            except Exception:
+                self.kv.release(slot)   # a failed (e.g. tampered) restore must
+                raise                   # not leak the slot or its reservation
+            # the WHOLE restore succeeded: only now are this sealed dict's
+            # shared-page references spent (a rolled-back restore must leave
+            # _sealed_refs and parked ciphertext intact for co-sharers)
+            self.kv.discard_sealed(key, sealed, prefix)
+            self.scheduler.running[slot] = req
+            self._active_mask[slot] = True
+            self._set_slot_sampling(slot, req)
+            # next decode input: the prompt tail (if chunked prefill was cut
+            # short) takes precedence in step(); otherwise the last output
+            # token.
+            self._last_token[slot] = req.output[-1] if req.output else 0
+            self.td.record_restore(sealed_nbytes(sealed), len(sealed),
+                                   f"slot={slot} rid={req.rid}")
+            self._drain_kv_events()
+            return slot
 
     def partial_preempt(self, slot: int, n_pages: int) -> None:
         """Page-granular preemption (paged backend only): seal the victim's

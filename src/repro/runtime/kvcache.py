@@ -166,6 +166,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.sealing import (SealedTensor, SealingKey, seal_tree,
                                 unseal_tree)
 from repro.runtime import sampling
@@ -632,9 +633,11 @@ class SlotDenseBackend(KVBackend):
                                  host_upload(slots, jnp.int32))
 
     def decode(self, params, tokens, state, kmax, write_slots) -> np.ndarray:
-        next_tokens, self.cache = self._decode_fn(
-            params, host_upload(tokens[:, None]), self.cache, state, kmax)
-        return np.asarray(next_tokens)
+        with obs.span("decode.prepare"):
+            next_tokens, self.cache = self._decode_fn(
+                params, host_upload(tokens[:, None]), self.cache, state, kmax)
+        with obs.span("decode.wait"):
+            return np.asarray(next_tokens)
 
     def cache_nbytes(self) -> int:
         return cache_bytes(self.cache)

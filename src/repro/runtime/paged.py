@@ -100,6 +100,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.sealing import (IntegrityError, SealedTensor, SealingKey,
                                 ciphertext_page_bytes, nonce_words_for,
                                 seal_tensor, shared_page_name, unseal_tensor,
@@ -892,29 +893,32 @@ class PagedKVBackend(KVBackend):
 
     def decode(self, params, tokens, state, kmax,
                write_slots: Sequence[int]) -> np.ndarray:
-        write_phys = np.zeros(self.max_slots, np.int32)   # default: null page
-        write_off = np.zeros(self.max_slots, np.int32)
-        for s in write_slots:
-            write_phys[s], write_off[s] = self._prepare_write(s)
-        if self.decode_mode == "kernel":
-            use_cipher = bool(self._cipher_pages)
-            key_words = (self._crypt_key.key_words if use_cipher
-                         else jnp.zeros(8, jnp.uint32))
-            next_tokens, self.blocks = self._decode_fn(
-                params, host_upload(tokens[:, None]), self.blocks,
-                host_upload(self.table), host_upload(self.pos),
-                host_upload(write_phys), host_upload(write_off),
-                host_upload(self._crypt[self._k_path]),
-                host_upload(self._crypt[self._v_path]),
-                key_words, state, kmax, use_cipher)
-        else:
-            next_tokens, self.blocks = self._decode_fn(
-                params, host_upload(tokens[:, None]), self.blocks,
-                host_upload(self.table), host_upload(self.pos),
-                host_upload(write_phys), host_upload(write_off), state, kmax)
-        for s in write_slots:
-            self.pos[s] += 1
-        return np.asarray(next_tokens)
+        with obs.span("decode.prepare"):
+            write_phys = np.zeros(self.max_slots, np.int32)   # null page
+            write_off = np.zeros(self.max_slots, np.int32)
+            for s in write_slots:
+                write_phys[s], write_off[s] = self._prepare_write(s)
+            if self.decode_mode == "kernel":
+                use_cipher = bool(self._cipher_pages)
+                key_words = (self._crypt_key.key_words if use_cipher
+                             else jnp.zeros(8, jnp.uint32))
+                next_tokens, self.blocks = self._decode_fn(
+                    params, host_upload(tokens[:, None]), self.blocks,
+                    host_upload(self.table), host_upload(self.pos),
+                    host_upload(write_phys), host_upload(write_off),
+                    host_upload(self._crypt[self._k_path]),
+                    host_upload(self._crypt[self._v_path]),
+                    key_words, state, kmax, use_cipher)
+            else:
+                next_tokens, self.blocks = self._decode_fn(
+                    params, host_upload(tokens[:, None]), self.blocks,
+                    host_upload(self.table), host_upload(self.pos),
+                    host_upload(write_phys), host_upload(write_off), state,
+                    kmax)
+            for s in write_slots:
+                self.pos[s] += 1
+        with obs.span("decode.wait"):
+            return np.asarray(next_tokens)
 
     def cache_nbytes(self) -> int:
         return sum(l.size * l.dtype.itemsize
