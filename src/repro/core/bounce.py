@@ -13,13 +13,20 @@ costs exactly (fig04/fig11 harnesses).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+from typing import Dict, List, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro import obs
 from repro.core.sealing import (IntegrityError, SealingKey, SealedTensor,
-                                seal_tensor, unseal_to_host)
+                                _mac, nonce_words_for, seal_tensor,
+                                unseal_to_host, verify_mac)
+from repro.kernels.chacha20 import xor_lanes
+
+# lanes of the frame program: up to this many blocks (a step's 16 one-token
+# frames and more) share one compiled shape; beyond, a power of two of it
+FRAME_LANES = 128
 
 
 @dataclasses.dataclass
@@ -30,7 +37,8 @@ class ChannelStats:
     are equal; a coalescing FramePolicy drives messages_out/tokens_out
     toward 1/N, which is exactly the amortization curve serve_bench plots.
     ``bytes_*`` count the plaintext; ``cipher_bytes_*`` the padded
-    ciphertext each message moved and MAC'd (whole ChaCha20 tiles)."""
+    ciphertext each message moved and MAC'd: whole 64 KiB ChaCha20 tiles
+    for ingress, whole 64-byte blocks for egress frames."""
     messages_in: int = 0
     messages_out: int = 0
     bytes_in: int = 0
@@ -99,6 +107,8 @@ class TokenFrame:
     a coalescing FramePolicy packs N tokens into one frame to amortize it.
     ``(stream_id, seq)`` is bound into the sealed tensor's name, so the nonce
     is unique per frame and the host side can detect replay or reordering.
+    Its ciphertext is its own whole ChaCha20 blocks, ``[16, ceil(n/64)]``
+    words for ``n`` bytes: the first blocks of the tensor sealed whole.
     """
     stream_id: int
     seq: int
@@ -128,6 +138,7 @@ class BounceBuffer:
         # later closure, so abandon streams with close_stream, not silence.
         self._closed_lo = 0
         self._closed_set: set = set()
+        self._key_words = None   # the cipher key on the device, for frames
 
     # host -> device
     def host_send(self, tokens: np.ndarray) -> SealedTensor:
@@ -169,34 +180,82 @@ class BounceBuffer:
     def _stream_closed(self, stream_id: int) -> bool:
         return stream_id < self._closed_lo or stream_id in self._closed_set
 
-    # device -> host, streaming: one frame per FramePolicy flush (1..N tokens)
+    # device -> host, streaming: one frame per FramePolicy flush (1..N
+    # tokens); the frames due together cross in one device trip each way
+    def device_send_frames(self, frames: Sequence[Tuple[int, np.ndarray]]
+                           ) -> List[TokenFrame]:
+        """Seal ``frames`` (``(stream_id, tokens)`` pairs), each under its
+        own stream seq, name, nonce and MAC, in one device call: one upload
+        of every frame's blocks with each lane's nonce and counter, one
+        XOR with the keystream, one read-back; then a MAC per frame."""
+        for stream_id, _ in frames:
+            if self._stream_closed(stream_id):
+                raise IntegrityError(
+                    f"stream {stream_id} is closed; sending would restart "
+                    f"its seq at 0 and reuse a nonce")
+        pending = []
+        for stream_id, tokens in frames:
+            tokens = np.asarray(tokens, np.int32)
+            seq = self._stream_seq.get(stream_id, 0)
+            self._stream_seq[stream_id] = seq + 1
+            pending.append((stream_id, seq, tokens))
+        obs.count("egress.batches")
+        obs.count("egress.frames", len(pending))
+        out = []
+        with obs.span("frame.seal", frames=len(pending),
+                      streams=_streams(sid for sid, _ in frames)):
+            with obs.span("seal.kernel"):
+                cts = self._xor_frames(
+                    [(TokenFrame.frame_name(sid, seq), _blocks(toks))
+                     for sid, seq, toks in pending])
+            for (sid, seq, toks), ct in zip(pending, cts):
+                st = SealedTensor(name=TokenFrame.frame_name(sid, seq),
+                                  ciphertext=ct, mac=b"", shape=toks.shape,
+                                  dtype=str(toks.dtype), n_bytes=toks.nbytes)
+                with obs.span("seal.mac"):
+                    st.mac = _mac(self.key, st.header(), ct)
+                self.stats.messages_out += 1
+                self.stats.bytes_out += st.n_bytes
+                self.stats.cipher_bytes_out += ct.nbytes
+                self.stats.tokens_out += int(toks.size)
+                out.append(TokenFrame(sid, seq, st))
+        return out
+
+    def host_recv_frames(self, frames: Sequence[TokenFrame]
+                         ) -> List[np.ndarray]:
+        """Open ``frames``: every frame's stream, name, seq, size and MAC
+        are checked before anything is decrypted, then one device call
+        decrypts them all. A bad frame anywhere raises
+        :class:`IntegrityError` and advances no stream's seq."""
+        with obs.span("frame.open", frames=len(frames),
+                      streams=_streams(f.stream_id for f in frames)):
+            want: Dict[int, int] = {}
+            for frame in frames:
+                self._check_frame(frame, want)
+            with obs.span("unseal.kernel"):
+                pts = self._xor_frames([(f.sealed.name, f.sealed.ciphertext)
+                                        for f in frames])
+            out = [_unblock(pt, f.sealed) for pt, f in zip(pts, frames)]
+            # advance only after every MAC verified: a forged frame must not
+            # burn the seq and lock out the authentic one behind it.
+            self._stream_recv.update(want)
+        return out
+
     def device_send_frame(self, stream_id: int, tokens: np.ndarray) -> TokenFrame:
-        if self._stream_closed(stream_id):
-            raise IntegrityError(
-                f"stream {stream_id} is closed; sending would restart its "
-                f"seq at 0 and reuse a nonce")
-        tokens = np.asarray(tokens, np.int32)
-        seq = self._stream_seq.get(stream_id, 0)
-        self._stream_seq[stream_id] = seq + 1
-        name = TokenFrame.frame_name(stream_id, seq)
-        with obs.span("frame.seal", stream=stream_id):
-            sealed = seal_tensor(self.key, name, tokens)
-        self.stats.messages_out += 1
-        self.stats.bytes_out += sealed.n_bytes
-        self.stats.cipher_bytes_out += sealed.ciphertext.nbytes
-        self.stats.tokens_out += int(tokens.size)
-        return TokenFrame(stream_id, seq, sealed)
+        return self.device_send_frames([(stream_id, tokens)])[0]
 
     def host_recv_frame(self, frame: TokenFrame) -> np.ndarray:
-        with obs.span("frame.open", stream=frame.stream_id):
-            return self._open_frame(frame)
+        return self.host_recv_frames([frame])[0]
 
-    def _open_frame(self, frame: TokenFrame) -> np.ndarray:
-        if self._stream_closed(frame.stream_id):
+    def _check_frame(self, frame: TokenFrame, want: Dict[int, int]) -> None:
+        """One frame's checks before decryption; ``want`` holds the next seq
+        of each stream seen earlier in the batch."""
+        sid = frame.stream_id
+        if self._stream_closed(sid):
             raise IntegrityError(
-                f"stream {frame.stream_id} is closed "
+                f"stream {sid} is closed "
                 f"(replayed frame from a finished request)")
-        expect = TokenFrame.frame_name(frame.stream_id, frame.seq)
+        expect = TokenFrame.frame_name(sid, frame.seq)
         if frame.sealed.name != expect:
             raise IntegrityError(
                 f"frame name mismatch: got '{frame.sealed.name}', "
@@ -204,16 +263,41 @@ class BounceBuffer:
         # strict in-order receive per stream: a verbatim-replayed or
         # reordered frame carries a stale seq and is rejected even though
         # its MAC verifies.
-        want = self._stream_recv.get(frame.stream_id, 0)
-        if frame.seq != want:
+        seq = want.get(sid, self._stream_recv.get(sid, 0))
+        if frame.seq != seq:
             raise IntegrityError(
-                f"stream {frame.stream_id}: got frame seq {frame.seq}, "
-                f"expected {want} (replayed or reordered frame)")
-        out = unseal_to_host(self.key, frame.sealed)
-        # advance only after the MAC verified: a forged frame must not burn
-        # the seq and lock out the authentic one behind it.
-        self._stream_recv[frame.stream_id] = want + 1
-        return out
+                f"stream {sid}: got frame seq {frame.seq}, "
+                f"expected {seq} (replayed or reordered frame)")
+        verify_mac(self.key, frame.sealed)
+        shape = frame.sealed.ciphertext.shape
+        if shape != (16, _n_blocks(frame.sealed.n_bytes)):
+            raise IntegrityError(
+                f"frame '{expect}' holds {shape} words, not the blocks of "
+                f"its {frame.sealed.n_bytes} bytes")
+        want[sid] = seq + 1
+
+    def _xor_frames(self, frames: Sequence[Tuple[str, np.ndarray]]
+                    ) -> List[np.ndarray]:
+        """``(name, blocks [16, n])`` pairs XORed with each name's keystream
+        (its nonce, block counters from 0) in one device call; frame by
+        frame, the result's blocks. Seal and open alike."""
+        tiles = max(1, -(-sum(b.shape[1] for _, b in frames) // FRAME_LANES))
+        lanes = np.zeros((20, FRAME_LANES << (tiles - 1).bit_length()),
+                         np.uint32)
+        placed = []
+        a = 0
+        for name, blocks in frames:
+            n = blocks.shape[1]
+            lanes[:16, a:a + n] = blocks
+            lanes[16:19, a:a + n] = nonce_words_for(self.key, name)[:, None]
+            lanes[19, a:a + n] = np.arange(n, dtype=np.uint32)
+            placed.append((a, n))
+            a += n
+        if self._key_words is None:
+            self._key_words = jax.device_put(
+                np.frombuffer(self.key.key, np.uint32))
+        out = np.asarray(xor_lanes(self._key_words, lanes))
+        return [np.ascontiguousarray(out[:, a:a + n]) for a, n in placed]
 
     def close_stream(self, stream_id: int) -> None:
         """Retire a finished stream: its per-stream seq state is dropped
@@ -230,3 +314,29 @@ class BounceBuffer:
         """Convenience: host->device one message (tests/benchmarks)."""
         sealed = self.host_send(tokens)
         return self.device_recv(sealed), sealed
+
+
+def _n_blocks(n_bytes: int) -> int:
+    return -(-n_bytes // 64)
+
+
+def _blocks(array: np.ndarray) -> np.ndarray:
+    """An array's bytes as whole ChaCha20 blocks, uint32 [16, n]: the
+    blocked layout of ``ops.pack_u32`` without its padding to a tile."""
+    raw = np.ascontiguousarray(array).reshape(-1).view(np.uint8)
+    buf = np.zeros(_n_blocks(raw.size) * 64, np.uint8)
+    buf[:raw.size] = raw
+    return buf.view("<u4").reshape(-1, 16).T
+
+
+def _unblock(blocks: np.ndarray, sealed: SealedTensor) -> np.ndarray:
+    """Inverse of ``_blocks`` for the tensor ``sealed``'s header names."""
+    raw = np.ascontiguousarray(blocks.T).astype("<u4").view(np.uint8)
+    return (raw.reshape(-1)[:sealed.n_bytes].view(np.dtype(sealed.dtype))
+            .reshape(sealed.shape))
+
+
+def _streams(stream_ids) -> str:
+    """A batch's streams, as its spans carry them: space-separated (the
+    profiler reads ``"2,3"`` as the number 2)."""
+    return " ".join(map(str, stream_ids))

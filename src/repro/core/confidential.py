@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -58,6 +58,9 @@ class TrustDomain:
         self._model_digest = ""
         self._code_hash: Optional[str] = None
         self._tenant_keys: Dict[str, sealing.SealingKey] = {}
+        # the step's egress frames, staged and then opened in one batch
+        self._staged: Dict[int, List[int]] = {}
+        self._opened: Optional[Dict[int, List[int]]] = None
 
     # -- audit ---------------------------------------------------------------
     def _log(self, kind: str, detail: str = ""):
@@ -140,22 +143,65 @@ class TrustDomain:
         self._log("egress", f"{sealed.n_bytes}B")
         return out
 
+    def stage_egress(self, frames: Sequence[Tuple[int, Sequence[int]]]
+                     ) -> None:
+        """Record the frames (``(stream_id, tokens)`` pairs) due in one
+        engine step, so that they cross the boundary in one device trip
+        each way: host bookkeeping only. Each must then be requested
+        through :meth:`egress_tokens` before :meth:`finish_egress`."""
+        if not self.confidential:
+            return
+        self.finish_egress()
+        self._staged = {sid: [int(t) for t in toks] for sid, toks in frames}
+        if len(self._staged) != len(frames):
+            self._staged = {}
+            raise ValueError("two staged frames of one stream")
+
+    def finish_egress(self) -> None:
+        """End of the step's egress: no staged frame may outlive it."""
+        left, self._staged, self._opened = list(self._staged), {}, None
+        if left:
+            raise RuntimeError(
+                f"staged egress frames of streams {left} were never sent")
+
     def egress_tokens(self, stream_id: int, tokens) -> List[int]:
         """Trust domain -> host, streaming: ONE encrypted frame carrying
         ``tokens`` (a FramePolicy flush — 1 token per frame in the
         SecureChat-style default, N when coalescing). Each frame pays the
         fixed per-crossing cost the cgpu profile's ``fixed_boundary_s``
         models, so ``ChannelStats`` sees crossings (messages_out) and the
-        tokens they amortize over (tokens_out) separately."""
-        toks = np.asarray(tokens, np.int32).reshape(-1)
+        tokens they amortize over (tokens_out) separately.
+
+        A staged frame's first request sends and opens every staged frame
+        in one batch and returns its own tokens; later ones return theirs.
+        An unstaged frame is a batch of one."""
+        toks = [int(t) for t in np.asarray(tokens, np.int32).reshape(-1)]
         if not self.confidential:
-            return [int(t) for t in toks]
-        frame = self.channel.device_send_frame(stream_id, toks)
-        out = self.channel.host_recv_frame(frame)
-        self._log("egress_frame",
-                  f"stream={stream_id} seq={frame.seq} n={toks.size} "
-                  f"{frame.sealed.n_bytes}B")
-        return [int(t) for t in out]
+            return toks
+        staged = self._staged.get(stream_id)
+        if staged is None:
+            return self._egress_batch([(stream_id, toks)])[0]
+        if staged != toks:
+            raise ValueError(
+                f"stream {stream_id}: tokens differ from the staged frame's")
+        if self._opened is None:
+            try:
+                self._opened = dict(zip(self._staged, self._egress_batch(
+                    list(self._staged.items()))))
+            except Exception:
+                self._staged = {}   # leave nothing staged behind the error
+                raise
+        del self._staged[stream_id]
+        return self._opened.pop(stream_id)
+
+    def _egress_batch(self, frames) -> List[List[int]]:
+        sent = self.channel.device_send_frames(frames)
+        opened = self.channel.host_recv_frames(sent)
+        for frame, (_, toks) in zip(sent, frames):
+            self._log("egress_frame",
+                      f"stream={frame.stream_id} seq={frame.seq} "
+                      f"n={len(toks)} {frame.sealed.n_bytes}B")
+        return [[int(t) for t in out] for out in opened]
 
     def egress_token(self, stream_id: int, token: int) -> int:
         """Single-token convenience wrapper over :meth:`egress_tokens`."""

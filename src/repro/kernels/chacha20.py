@@ -75,6 +75,20 @@ def chacha_block_words(key_words, nonce_words, counters):
     return [s + i for s, i in zip(state, init)]
 
 
+@jax.jit
+def xor_lanes(key: jax.Array, lanes: jax.Array) -> jax.Array:
+    """XOR blocks that each carry their own nonce and block counter.
+
+    ``lanes`` is uint32 [20, L]: rows 0-15 are the blocks' data words (the
+    blocked layout), rows 16-18 each lane's nonce words and row 19 its block
+    counter. Returns the data XOR the keystream, [16, L]. Many short
+    messages, each under its own nonce, cross in one call this way; plain
+    XLA code, as a few KiB need no tiling."""
+    words = chacha_block_words([key[i] for i in range(8)],
+                               [lanes[16], lanes[17], lanes[18]], lanes[19])
+    return lanes[:16] ^ jnp.stack(words)
+
+
 def _xor_kernel(base_ref, key_ref, nonce_ref, data_ref, out_ref):
     """One tile: data (16, BLOCKS) uint32 XOR keystream for counters
     [base + pid*BLOCKS, ...)."""

@@ -3,8 +3,8 @@
 ``span(name, **meta)`` marks a stretch of host work. With tracing enabled
 it is a ``jax.profiler.TraceAnnotation``: the span lands on the profiler's
 host plane, on the same clock as the device planes, with ``meta`` as its
-arguments (``stream=`` on egress frames, ``rows=``/``bucket=`` on
-prefills). Disabled, it returns one shared null context: nothing is
+arguments (``frames=`` and ``streams=`` on a batch of egress frames,
+``rows=``/``bucket=`` on prefills). Disabled, it returns one shared null context: nothing is
 allocated for it and the profiler is never touched. ``enable()`` and
 ``disable()`` switch it; tracing starts disabled.
 

@@ -325,6 +325,7 @@ class Engine:
             self.kv.bind_store_key(self.td.sealing_key)
         self._active_mask = np.zeros(max_slots, bool)
         self._last_token = np.zeros(max_slots, np.int32)
+        self._due: List[Request] = []   # requests whose frame is due (_emit)
         self._preempted: List[PreemptedRequest] = []
         self._paused: Dict[int, PausedSlot] = {}
         self._buckets: Dict[int, _RateBucket] = {
@@ -670,9 +671,10 @@ class Engine:
                 req.on_token(req, int(t))
 
     def _emit_token(self, slot: int, tok: int) -> bool:
-        """Record one sampled token (in-domain), egress per the request's
-        FramePolicy (coalesce window, flush-on-finish), and check
-        termination. Returns True if the request finished."""
+        """Record one sampled token (in-domain), mark the request's frame
+        due per its FramePolicy (coalesce window, flush-on-finish), and
+        check termination. Returns True if the request finished; ``_emit``
+        sends the due frames and releases the finished."""
         req = self.scheduler.running[slot]
         self.scheduler.record_token(slot, int(tok))
         # penalty history (host), counted only for penalized slots; a
@@ -684,17 +686,29 @@ class Engine:
         done = req.done
         req.egress_buf.append(int(tok))
         if done or not self.td.confidential or len(req.egress_buf) >= req.coalesce:
+            self._due.append(req)
+        return done
+
+    def _emit(self, tokens: Sequence[Tuple[int, int]]) -> None:
+        """Emit ``(slot, token)`` pairs, in three passes: (a) each token is
+        recorded and its request's frame marked due; (b) the due frames are
+        staged, so they cross the trust boundary in one device trip each
+        way, and each is requested in slot order, its ``on_token`` after
+        it; (c) finished requests are released, a stream closed only after
+        its last frame has left. Finishing checks right after recording: a
+        max_new_tokens=1 request (or EOS as the very first token) releases
+        its slot without paying for a wasted decode step."""
+        finished = [slot for slot, tok in tokens if self._emit_token(slot, tok)]
+        due, self._due = self._due, []
+        self.td.stage_egress([(req.stream_id, req.egress_buf) for req in due])
+        for req in due:
             self._flush_egress(req)
-        if done:
-            # check immediately after recording: a max_new_tokens=1 request
-            # (or EOS as the very first token) releases its slot without
-            # paying for a wasted decode step (v1 off-by-one).
-            self.scheduler.finish(slot)
+        self.td.finish_egress()
+        for slot in finished:
+            req = self.scheduler.finish(slot)
             self.kv.release(slot)
             self._active_mask[slot] = False
             self.td.close_stream(req.stream_id)
-            return True
-        return False
 
     # -- SLO admission ---------------------------------------------------------
     @property
@@ -822,8 +836,8 @@ class Engine:
         with obs.span("step.insert"):
             self.kv.insert_prefill(prefilled, slots, bucket,
                                    page_keys=group_keys)
-        for i, req in enumerate(group):
-            self._start_decode(slots[i], req, int(first_np[i]), bucket)
+        self._emit([(slots[i], int(first_np[i])) for i, req in enumerate(group)
+                    if self._start_decode(slots[i], req, bucket)])
         return len(group)
 
     @staticmethod
@@ -834,12 +848,11 @@ class Engine:
         obs.count("engine.prefill_pad_rows", rows - real_rows)
         obs.count("engine.prefill_pad_tokens", pad_tokens)
 
-    def _start_decode(self, slot: int, req: Request, first_tok: int,
-                      bucket: int) -> None:
+    def _start_decode(self, slot: int, req: Request, bucket: int) -> bool:
         """Common post-prefill setup: the request enters the decode phase —
         it joins the scheduler's running set, its sampling row is set, and
         either its chunked prompt tail starts feeding through decode steps
-        or its first sampled token is emitted."""
+        or (True) its first sampled token is to be emitted."""
         self.scheduler.start(slot, req)
         req.phase = "decode"
         self._active_mask[slot] = True
@@ -849,8 +862,8 @@ class Engine:
             # one token per step, before any sampling counts as output.
             req.pending_input = [int(t) for t in req.prompt[bucket:]]
             self._last_token[slot] = 0   # unused until the tail drains
-        else:
-            self._emit_token(slot, first_tok)
+            return False
+        return True
 
     def _first_tokens(self, logits, group: List[Request], rows: int) -> np.ndarray:
         """Sample each group member's first token from its prefill logits
@@ -917,7 +930,8 @@ class Engine:
         keys = [req.page_keys] if self.kv.supports_sharing else None
         with obs.span("step.insert"):
             self.kv.insert_prefill(prefilled, [slot], bucket, page_keys=keys)
-        self._start_decode(slot, req, int(first_np[0]), bucket)
+        if self._start_decode(slot, req, bucket):
+            self._emit([(slot, int(first_np[0]))])
 
     def _admit_continuous(self) -> int:
         """Step-level admission: pop waiting requests one at a time into
@@ -1020,6 +1034,7 @@ class Engine:
                             f"handoff x{len(group)} rids={rids}")
         self.td.record_restore(total_nb, total_tensors,
                                f"handoff x{len(group)} rids={rids}")
+        firsts = []
         for inf, prefix, sealed in sealed_rows:
             req, slot, bucket = inf.req, inf.slot, inf.bucket
             restored = unseal_tree(self.td.sealing_key, sealed,
@@ -1030,7 +1045,9 @@ class Engine:
                 self.kv.insert_prefill(restored, [slot], bucket,
                                        page_keys=keys)
             first_np = self._first_tokens(inf.logits, [req], 1)
-            self._start_decode(slot, req, int(first_np[0]), bucket)
+            if self._start_decode(slot, req, bucket):
+                firsts.append((slot, int(first_np[0])))
+        self._emit(firsts)
 
     def _preempt_for(self, incoming: Request) -> bool:
         """Free capacity for ``incoming`` by preempting the lowest-priority
@@ -1267,22 +1284,19 @@ class Engine:
             n, cb, cs = self.plan.drain_collectives()
             if n:
                 self.td.record_collective(cb, cs, steps=n)
-        produced = 0
+        # a mid-prompt chunk's sample is discarded
+        tokens = [(slot, int(next_np[slot])) for slot in live
+                  if self._active_mask[slot] and slot not in self._paused
+                  and not feeding_prompt.get(slot, False)]
         with obs.span("step.emit"):
-            for slot in list(live):
-                if not self._active_mask[slot] or slot in self._paused:
-                    continue
-                if feeding_prompt.get(slot, False):
-                    continue   # mid-prompt chunk: its sample is discarded
-                self._emit_token(slot, int(next_np[slot]))
-                produced += 1
+            self._emit(tokens)
         self._drain_kv_events()
         # feasibility floor: only steps that actually decoded count (an
         # empty tick would fake an impossibly fast step and over-reject)
         dt = time.monotonic() - t0
         if self._step_floor is None or dt < self._step_floor:
             self._step_floor = dt
-        return produced
+        return len(tokens)
 
     @property
     def idle(self) -> bool:

@@ -25,7 +25,8 @@ if str(REPO) not in sys.path:
 
 from bench import program_spans, readers  # noqa: E402
 
-TILE_BYTES = 65536   # one ChaCha20 tile: what a small frame pads to
+TILE_BYTES = 65536   # one ChaCha20 tile: what an ingress message pads to
+BLOCK_BYTES = 64     # one ChaCha20 block: what a one-token frame pads to
 
 # each span's innermost enclosing program span, as the engine nests them
 # (None: opened outside any program span, e.g. ingress at submit)
@@ -175,14 +176,28 @@ def test_program_spans_nest_as_the_engine_does(traced_run):
 
 
 def test_frame_spans_carry_their_stream(traced_run):
+    """One ``frame.seal`` and one ``frame.open`` per batch: per step that
+    emitted, and per prefill whose first tokens left; their ``frames`` add
+    up to one frame per token."""
     spans = traced_run["rec"]["program_spans"]
     streams = {r.stream_id for r in traced_run["reqs"]}
-    frames = [s for s in spans if s[0] in ("frame.seal", "frame.open")]
-    assert frames and {s[3]["stream"] for s in frames} == streams
     tokens = sum(len(r.output) for r in traced_run["reqs"])
-    assert len(frames) == 2 * tokens     # one frame per token, each way
     prefill = [s for s in spans if s[0] == "step.prefill"]
     assert [(s[3]["rows"], s[3]["bucket"]) for s in prefill] == [(2, 8)]
+    batches = traced_run["steps"] + len(prefill)
+    for name in ("frame.seal", "frame.open"):
+        frames = [s for s in spans if s[0] == name]
+        assert len(frames) == batches, name
+        assert {int(sid) for s in frames
+                for sid in str(s[3]["streams"]).split()} == streams
+        assert sum(s[3]["frames"] for s in frames) == tokens
+    for name in ("seal.kernel", "unseal.kernel"):   # one trip per batch
+        assert len([s for s in spans if s[0] == name
+                    and not any(i[0].startswith("ingress.") and i[1] <= s[1]
+                                and s[2] <= i[2] for i in spans)]) == batches
+    now, was = obs.counters(), traced_run["before"]
+    assert now["egress.batches"] - was.get("egress.batches", 0) == batches
+    assert now["egress.frames"] - was.get("egress.frames", 0) == tokens
 
 
 def test_engine_counters_match_the_engine(traced_run):
@@ -204,7 +219,7 @@ def test_cipher_bytes_are_whole_tiles_per_message(traced_run):
     was = traced_run["before"]
     frames = stats.messages_out - was["frames"]
     assert frames == sum(len(r.output) for r in traced_run["reqs"])
-    assert stats.cipher_bytes_out - was["cipher_out"] == frames * TILE_BYTES
+    assert stats.cipher_bytes_out - was["cipher_out"] == frames * BLOCK_BYTES
     assert (stats.cipher_bytes_in - was["cipher_in"]
             == (stats.messages_in - was["messages_in"]) * TILE_BYTES)
     assert stats.bytes_out < stats.cipher_bytes_out

@@ -17,7 +17,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.chacha20 import BLOCKS_PER_TILE, chacha20_xor_blocked
+from repro.core.bounce import FRAME_LANES
+from repro.kernels.chacha20 import (BLOCKS_PER_TILE, chacha20_xor_blocked,
+                                     xor_lanes)
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.paged_attention import (paged_attention,
                                            paged_attention_unseal)
@@ -60,6 +62,13 @@ def test_chacha20_xor_compiles(one_chip):
         ((8,), jnp.uint32), ((3,), jnp.uint32),
         ((16, 64 * BLOCKS_PER_TILE), jnp.uint32), ((), jnp.uint32))
     assert "tpu_custom_call" in hlo
+
+
+def test_egress_frame_program_compiles(one_chip):
+    """A step's egress frames, one 128-lane call (plain XLA, no kernel)."""
+    hlo = _compiled_text(one_chip, xor_lanes, ((8,), jnp.uint32),
+                         ((20, FRAME_LANES), jnp.uint32))
+    assert "tpu_custom_call" not in hlo
 
 
 def test_flash_attention_compiles(one_chip):
