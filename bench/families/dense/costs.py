@@ -10,7 +10,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
-from bench.weights import dims
+from .weights import dims
 
 ITEMSIZE = {"bfloat16": 2, "float32": 4}
 
@@ -72,3 +72,8 @@ def paged_attention_cost(cfg: dict, ctx: Sequence[int]):
     nbytes = (2 * m["hk"] * m["hd"] * it * sum(ctx)
               + 2 * len(ctx) * m["h"] * m["hd"] * it)
     return flops, nbytes
+
+
+def paged_attention_layers(cfg: dict) -> int:
+    """Layers whose decode attention runs the paged kernel: every one."""
+    return dims(cfg)["layers"]

@@ -2,9 +2,12 @@
 
 ``BENCHMARK.json`` names each cell's configuration and traffic mix; the
 harness reads ``bench/configs/<config>.json`` (through the entry's
-``file``), ``bench/traffic/<traffic>.json`` and, in a traced run,
-``bench/metrics/<metric>.py`` for every per-layer metric the cell reports.
-A new model, mix or metric is a new file; nothing here names one.
+``file``), the model family that file names, ``bench/families/<family>/``
+(the program's configuration and weights, the plain reference's layers,
+the counts of operations and bytes), ``bench/traffic/<traffic>.json`` and,
+in a traced run, ``bench/metrics/<metric>.py`` for every per-layer metric
+the cell reports. A new family, model, mix or metric is new files; nothing
+here names one.
 
 A run: weights from the seed on the device; in a confidential mode the
 trust domain seals them, the plaintext is dropped, ``load_sealed`` brings
@@ -14,18 +17,21 @@ The window drives ``Engine.step`` and ``Engine.submit`` as a client
 would. Tokens are timed when ``on_token`` fires, after egress has left
 the trust domain; latencies count from when a request was due. After the
 window the program's state is freed and the plain reference checks the
-greedy requests' tokens (``bench/reference.py``).
+greedy requests' tokens (``bench/reference.py`` with the family's layers).
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
+import re
 import resource
 import shutil
+import sys
 import tempfile
 import time
 from pathlib import Path
@@ -33,7 +39,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from bench import loadgen, reference, trace_reduce, weights
+from bench import loadgen, reference, trace_reduce
 
 ROOT = Path(__file__).resolve().parents[1]
 COMPILE_EVENTS = ("/jax/core/compile/backend_compile_duration",
@@ -42,13 +48,15 @@ TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
 # a traced run profiles the window's last seconds (and, in an open loop,
 # the drain after it); the profiler's cost grows with what it records
 TRACE_SECONDS = 15.0
-# configuration-file keys -> the program's ModelConfig fields
-MODEL_KEYS = {"num_hidden_layers": "num_layers", "hidden_size": "d_model",
-              "num_attention_heads": "num_heads",
-              "num_key_value_heads": "num_kv_heads", "head_dim": "head_dim",
-              "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-              "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
-              "dtype": "dtype", "tie_word_embeddings": "tie_embeddings"}
+# what a family gives: the program's configuration and weights; the plain
+# reference's kind of each layer, weights of one layer, embedding and final
+# norm, and its float32 layer forward, embedding lookup, final norm and
+# output head; the counts of operations and bytes the per-layer readers use
+FAMILY_PARTS = ("model_config", "program_params",
+                "layer_kind", "layer", "embedding", "final_norm",
+                "layer_forward", "embed", "norm", "logits",
+                "decode_step_bytes", "paged_attention_cost",
+                "paged_attention_layers", "token_flops", "prefill_flops")
 
 
 class NoDevice(RuntimeError):
@@ -74,6 +82,12 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     root: Path
+
+    @functools.cached_property
+    def family(self):
+        """The model family the configuration names, loaded from the
+        checkout."""
+        return load_family(self.root, self.config["family"])
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
@@ -103,6 +117,26 @@ def load_metric(root: Path, name: str):
     spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    return mod
+
+
+def load_family(root: Path, name: str):
+    """``bench/families/<name>/`` as a package of its own (its modules
+    import each other relatively), loaded afresh: two checkouts in one
+    process may each hold a family of that name."""
+    path = root / "bench" / "families" / name
+    mod_name = "bench_family_" + re.sub(r"\W", "_", name)
+    for k in [k for k in sys.modules
+              if k == mod_name or k.startswith(mod_name + ".")]:
+        del sys.modules[k]
+    spec = importlib.util.spec_from_file_location(
+        mod_name, path / "__init__.py", submodule_search_locations=[str(path)])
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod
+    spec.loader.exec_module(mod)
+    missing = [p for p in FAMILY_PARTS if not callable(getattr(mod, p, None))]
+    if missing:
+        raise AttributeError(f"family {name!r} ({path}) lacks {missing}")
     return mod
 
 
@@ -220,6 +254,10 @@ class RunData:
     def config(self) -> dict:
         return self.cell.config
 
+    @property
+    def family(self):
+        return self.cell.family
+
     def window_steps(self) -> List[StepRec]:
         return [s for s in self.steps if s.t0 >= self.t0 and s.t1 <= self.t1]
 
@@ -263,11 +301,8 @@ def check_devices(cell: Cell, require_tpu: bool) -> dict:
 
 
 def model_config(cell: Cell):
-    from repro.configs import get_config
-    cfg = cell.config
-    over = {MODEL_KEYS[k]: cfg[k] for k in MODEL_KEYS if k in cfg}
-    return dataclasses.replace(get_config(cfg["model"]), name=cell.config_name,
-                               **over)
+    return dataclasses.replace(cell.family.model_config(cell.config),
+                               name=cell.config_name)
 
 
 @dataclasses.dataclass
@@ -287,16 +322,17 @@ def build(cell: Cell, seed: int) -> Served:
     from repro.runtime.engine import Engine
     mc = model_config(cell)
     model = Model(mc)
+    make = cell.family.program_params
     wseed = int(np.random.default_rng([seed, 0]).integers(2 ** 31 - 1))
-    shapes = jax.eval_shape(lambda: weights.program_params(wseed, cell.config))
+    shapes = jax.eval_shape(lambda: make(wseed, cell.config))
     want = model.abstract_params()
     if jax.tree.structure(shapes) != jax.tree.structure(want) or any(
             (a.shape, a.dtype) != (b.shape, b.dtype)
             for a, b in zip(jax.tree.leaves(shapes), jax.tree.leaves(want))):
-        raise ValueError("bench/weights.py does not make the program's "
-                         f"parameter tree for {cell.config_name}")
+        raise ValueError(f"family {cell.config['family']!r} does not make the "
+                         f"program's parameter tree for {cell.config_name}")
     phases, t = {}, clock()
-    params = jax.block_until_ready(weights.program_params(wseed, cell.config))
+    params = jax.block_until_ready(make(wseed, cell.config))
     phases["weights"] = clock() - t
     td = TrustDomain(cell.config["tee"])
     if td.confidential:
@@ -576,8 +612,8 @@ def check(cell: Cell, seed: int, recs: List[Rec], control: bool = False) -> dict
     seqs = [np.concatenate([s, [greedy[i].req.output[-1]]]).astype(np.int32)
             for s, i in zip(seqs, pick)]
     wseed = int(np.random.default_rng([seed, 0]).integers(2 ** 31 - 1))
-    res = reference.gaps(wseed, cell.config, seqs, starts, control=control) \
-        if seqs else []
+    res = reference.gaps(cell.family, wseed, cell.config, seqs, starts,
+                         control=control) if seqs else []
     gaps = {k: [g for r in res for g in r[k]] for k in ("served", "control")}
     return {"egress_mismatch": egress_bad, "checked_requests": len(seqs),
             "checked_tokens": len(gaps["served"]),

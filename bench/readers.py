@@ -1,7 +1,9 @@
 """Arithmetic shared by the per-layer metric readers in ``bench/metrics``.
 
 Each reader takes the run's records (``harness.RunData``) and returns a
-number, or None when the run holds nothing for it to read.
+number, or None when the run holds nothing for it to read. Operations and
+bytes come from the counts of the model family the configuration names
+(``run.family``).
 """
 
 from __future__ import annotations
@@ -10,7 +12,7 @@ from typing import Optional
 
 import numpy as np
 
-from bench import costs, trace_reduce
+from bench import trace_reduce
 
 
 def pct(values, q: float) -> Optional[float]:
@@ -52,7 +54,8 @@ def decode_hbm_share(run) -> Optional[float]:
     steps = decode_steps(run)
     if t is None or not steps:
         return None
-    need = sum(costs.decode_step_bytes(run.config, s.ctx) for s in steps)
+    need = sum(run.family.decode_step_bytes(run.config, s.ctx)
+               for s in steps)
     return 100.0 * need / run.peaks["hbm_bytes_per_s"] / t
 
 
@@ -63,10 +66,10 @@ def paged_attn_roofline(run) -> Optional[float]:
     steps = decode_steps(run)
     if t is None or not steps:
         return None
-    layers = run.config["num_hidden_layers"]
+    layers = run.family.paged_attention_layers(run.config)
     least = 0.0
     for s in steps:
-        flops, nbytes = costs.paged_attention_cost(run.config, s.ctx)
+        flops, nbytes = run.family.paged_attention_cost(run.config, s.ctx)
         least += layers * max(flops / run.peaks["bf16_flops_per_s"],
                               nbytes / run.peaks["hbm_bytes_per_s"])
     return 100.0 * least / t
@@ -78,11 +81,11 @@ def step_mfu(run) -> Optional[float]:
     steps = run.window_steps()
     if not steps:
         return None
-    flops = sum(costs.token_flops(run.config, c, True) for s in steps
+    flops = sum(run.family.token_flops(run.config, c, True) for s in steps
                 if s.ctx and s.tokens > s.first_tokens for c in s.ctx)
     first = {i for i, s in enumerate(run.steps)
              if s.t0 >= run.t0 and s.t1 <= run.t1}
-    flops += sum(costs.prefill_flops(run.config, len(r.spec.prompt))
+    flops += sum(run.family.prefill_flops(run.config, len(r.spec.prompt))
                  for r in run.requests if r.first_step in first)
     return 100.0 * flops / (run.seconds * run.peaks["bf16_flops_per_s"])
 

@@ -1,9 +1,10 @@
 """Fixtures for the benchmark's own tests: CPU, tiny sizes.
 
 ``make_root`` copies ``bench/`` and ``BENCHMARK.json`` into a temporary
-checkout and adds, as new files only, a tiny configuration, a traffic mix
-and a cell that uses them, plus a peaks entry for the CPU so the harness
-can run here with its look for a chip skipped.
+checkout and adds, as new files only, tiny configurations, traffic mixes
+and cells that use them, a model family (a copy of the dense one under
+another name) that one of the configurations names, plus a peaks entry for
+the CPU so the harness can run here with its look for a chip skipped.
 """
 
 from __future__ import annotations
@@ -43,15 +44,24 @@ TINY_LIMIT = 0.01
 TINY_MEAN_LIMIT = 0.001
 
 
+# a family added as new files only: the dense family's files under this name
+NEW_FAMILY = "dense_copy"
+
+
 def make_root(tmp: Path) -> Path:
-    """A checkout with cells ``tiny.open`` and ``tiny.closed`` added."""
-    shutil.copytree(REPO / "bench", tmp / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    """A checkout with cells ``tiny.open`` and ``tiny.closed`` added, and
+    ``tiny_copy.closed``, whose configuration names ``NEW_FAMILY``."""
+    ignore = shutil.ignore_patterns("__pycache__", "tests")
+    shutil.copytree(REPO / "bench", tmp / "bench", ignore=ignore)
+    shutil.copytree(REPO / "bench/families/dense",
+                    tmp / "bench/families" / NEW_FAMILY, ignore=ignore)
     cfg = json.loads((REPO / "bench/configs/mistral-nemo-12b-20l.json")
                      .read_text())
     cfg.update(TINY_MODEL, engine=TINY_ENGINE,
                checks={"logit_gap": TINY_LIMIT, "min_checked_tokens": 10})
     (tmp / "bench/configs/tiny.json").write_text(json.dumps(cfg))
+    (tmp / "bench/configs/tiny-copy.json").write_text(
+        json.dumps(dict(cfg, family=NEW_FAMILY)))
     for name, mix in TINY_MIXES.items():
         (tmp / f"bench/traffic/{name}.json").write_text(json.dumps(mix))
     peaks = json.loads((REPO / "bench/peaks.json").read_text())
@@ -60,13 +70,16 @@ def make_root(tmp: Path) -> Path:
                                "source": "placeholder for CPU tests"}
     (tmp / "bench/peaks.json").write_text(json.dumps(peaks))
     m = json.loads((REPO / "BENCHMARK.json").read_text())
-    m["configs"].append({"name": "tiny", "source": "test",
-                         "file": "bench/configs/tiny.json", "reduced": [],
-                         "why": "test"})
-    for kind, e2e in (("open", ("ttft_p75_ms", "itl_p50_ms", "itl_p95_ms")),
-                      ("closed", ("tokens_per_s",))):
-        cell = f"tiny.{kind}"
-        m["workloads"].append({"name": cell, "config": "tiny",
+    for name in ("tiny", "tiny-copy"):
+        m["configs"].append({"name": name, "source": "test",
+                             "file": f"bench/configs/{name}.json",
+                             "reduced": [], "why": "test"})
+    for config, kind, e2e in (
+            ("tiny", "open", ("ttft_p75_ms", "itl_p50_ms", "itl_p95_ms")),
+            ("tiny", "closed", ("tokens_per_s",)),
+            ("tiny-copy", "closed", ("tokens_per_s",))):
+        cell = f"{config.replace('-', '_')}.{kind}"
+        m["workloads"].append({"name": cell, "config": config,
                                "traffic": f"tiny_{kind}", "chips": 1,
                                "why": "test"})
         for x in m["end_to_end"]:

@@ -11,7 +11,8 @@ import sys
 import pytest
 
 from bench import harness
-from bench.tests.conftest import REPO, TINY_LIMIT, TINY_MEAN_LIMIT, make_root
+from bench.tests.conftest import (NEW_FAMILY, REPO, TINY_LIMIT,
+                                  TINY_MEAN_LIMIT, make_root)
 
 SEED = 2 ** 32 + 12345
 
@@ -50,19 +51,39 @@ def test_command_fails_with_only_the_benchmarks_files(tmp_path):
     assert p.returncode != 0 and "{" not in p.stdout
 
 
-def test_new_config_traffic_and_metric_run_from_new_files(tiny_root):
-    """The tiny configuration and mixes are new files under
-    bench/configs and bench/traffic; this adds a metric the same way."""
-    (tiny_root / "bench/metrics/tokens_seen.tiny.py").write_text(
+# appended to the new family's files: the reference draws layer 0's
+# down-projection a little off the program's
+PERTURB_REFERENCE = """
+
+import jax.numpy as jnp
+
+_drawn = layer
+
+
+def layer(key, cfg, i, dtype, kind):
+    lw = _drawn(key, cfg, i, dtype, kind)
+    lw["ffn"]["w_down"] = lw["ffn"]["w_down"] * jnp.where(i == 0, 1.25, 1.0)
+    return lw
+"""
+
+
+def test_new_config_traffic_and_metric_run_from_new_files(tmp_path):
+    """The tiny configurations and mixes are new files under bench/configs
+    and bench/traffic, and ``tiny_copy.closed``'s model family is a new
+    directory under bench/families; this adds a metric the same way. With
+    one weight of the new family's reference perturbed the same cell is not
+    correct: the check runs the family its configuration names."""
+    root = make_root(tmp_path)
+    (root / "bench/metrics/tokens_seen.tiny.py").write_text(
         'def read(run):\n'
         '    return sum(s.tokens for s in run.window_steps()) or None\n')
-    m = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    m = json.loads((root / "BENCHMARK.json").read_text())
     m["per_layer"].append({"name": "tokens_seen.tiny", "unit": "tokens",
                            "better": "higher", "source": "program_counter",
                            "layer": "device", "moves": "tokens_per_s",
-                           "workloads": ["tiny.closed"]})
-    (tiny_root / "BENCHMARK.json").write_text(json.dumps(m))
-    traced = run(tiny_root, "tiny.closed", trace=True)
+                           "workloads": ["tiny_copy.closed"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(m))
+    traced = run(root, "tiny_copy.closed", trace=True)
     assert traced["correct"] and traced["failed"] == 0
     assert traced["metrics"]["tokens_seen.tiny"]["value"] > 0
     assert traced["metrics"]["batch_occupancy.batch"]["value"] > 0
@@ -70,8 +91,15 @@ def test_new_config_traffic_and_metric_run_from_new_files(tiny_root):
                                      "memory_peak_bytes", "busy_s",
                                      "window_s"}
     assert list(traced)[-1] == "checks" and "breakdown" in traced
-    plain = run(tiny_root, "tiny.closed")
+    plain = run(root, "tiny_copy.closed")
+    assert plain["correct"], plain["checks"]
     assert set(plain["metrics"]) == {"tokens_per_s", "setup_s"}
+    with (root / "bench/families" / NEW_FAMILY / "__init__.py").open("a") as f:
+        f.write(PERTURB_REFERENCE)
+    broken = run(root, "tiny_copy.closed")
+    assert not broken["correct"]
+    assert broken["checks"]["logit_gap"]["value"] > TINY_LIMIT
+    assert broken["checks"]["egress_mismatch"]["value"] == 0
 
 
 def test_open_loop_cell_reports_its_latencies(tiny_root):

@@ -92,6 +92,15 @@ def test_configuration_files(entry):
                                       "", {}, [], [], REPO))
 
 
+@pytest.mark.parametrize("entry", M["configs"], ids=lambda c: c["name"])
+def test_configuration_names_a_family_that_gives_every_part(entry):
+    cfg = json.loads((REPO / entry["file"]).read_text())
+    assert NAME.match(cfg["family"])
+    assert (REPO / "bench/families" / cfg["family"] / "__init__.py").is_file()
+    family = harness.load_family(REPO, cfg["family"])
+    assert all(callable(getattr(family, p)) for p in harness.FAMILY_PARTS)
+
+
 @pytest.mark.parametrize("cell", M["workloads"], ids=lambda w: w["name"])
 def test_cells_load_and_pool_holds_slots_longest(cell):
     c = harness.load_cell(cell["name"], REPO)

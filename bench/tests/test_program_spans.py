@@ -109,7 +109,7 @@ def test_trace_program_on_the_cpu(tiny_root):
                             require_tpu=False, log=lambda s: None)
     assert obs.span("step") is obs.NULL     # tracing is off again
     p = out["program"]
-    assert p["egress_cipher_bytes_per_token"] == 65536
+    assert p["egress_cipher_bytes_per_token"] == 64     # one ChaCha20 block
     for k in ("egress_mac_ms_per_token", "egress_kernel_ms_per_token",
               "tee_ms_per_token", "step_prep_ms", "emit_idle_ms",
               "idle_in_tee_share", "traces_per_kstep"):
